@@ -62,8 +62,11 @@ def main() -> int:
     import importlib
     import os
 
+    from repro.launch import compile_cache
+
     from . import common
 
+    print(f"compile cache: {compile_cache.enable()}")
     only = set(args.only.split(",")) if args.only else None
     failures = []
     for title, mod_name in SECTIONS:

@@ -26,11 +26,11 @@ This module is the single implementation all of them drive:
   *visited filter* of ``core/visited.py`` (``visited_size > 0`` — the
   multi-expansion default, which also remembers evicted vertices, so
   ``evals`` can run below the broadcast engine's);
-* ``hop_backend="pallas"`` routes the whole hop body — adjacency-row
-  gather, visited filter, vector gather, distance, candidate compaction —
-  through the fused ``kernels/fused_hop`` Pallas kernel (requires the
-  visited filter and an exact float store); the per-hop beam merge
-  dispatches to ``kernels/beam_merge`` as before.
+* ``hop_backend="pallas"`` routes the hop body through ``kernels/fused_hop``:
+  the filter runs in XLA and only the surviving vector rows are copied
+  into VMEM and scored by the ``gather_dist`` kernel (requires the visited
+  filter, an exact float store and l2); the per-hop beam merge dispatches
+  to ``kernels/beam_merge`` as before.
 
 ``core/search.py::range_search`` is a thin jitted driver over this engine;
 see ARCHITECTURE.md ("Multi-expansion beam layering") for how the layers
@@ -201,10 +201,15 @@ def _select_unchecked(state: BeamState, expand_width: int):
             jnp.stack(un_list, axis=1))
 
 
-def _fused_hop_eligible(vectors, metric: str) -> bool:
-    """Static: can this hop lower to the fused_hop Pallas kernel?"""
+def _check_fused_hop(vectors, metric: str) -> None:
+    """The fused hop kernel scores exact float rows under l2 only; asking
+    for it anywhere else is a configuration error, not a silent jnp run."""
     store = as_store(vectors)
-    return store.exact and metric in ("l2", "sqeuclidean")
+    if not store.exact or metric not in ("l2", "sqeuclidean"):
+        raise ValueError(
+            "hop_backend='pallas' needs an exact float store and the l2 or "
+            f"sqeuclidean metric; got codec {store.codec!r}, metric "
+            f"{metric!r} (use hop_backend='jnp')")
 
 
 def expand(state: BeamState, adjacency: Array, n_valid: Array,
@@ -220,10 +225,9 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
 
     Dedup of freshly gathered neighbors is the seed beam broadcast when
     ``state.visited is None`` and the O(probes) visited filter otherwise.
-    ``hop_backend="pallas"`` fuses gather→filter→gather→distance→compaction
-    into ``kernels/fused_hop`` (visited filter + exact float store + l2
-    only; anything else statically falls back to the jnp composition, which
-    is bit-identical).
+    ``hop_backend="pallas"`` scores the hop's surviving neighbors with the
+    ``kernels/fused_hop`` kernel path (visited filter + exact float store +
+    l2 only; anything else raises ``ValueError``).
 
     ``hop_budget`` (B,) int32 caps each lane's expansions: a lane whose
     ``hops`` counter has reached its budget stops expanding (its beam is
@@ -252,9 +256,11 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
     checked = state.checked.at[lane[:, None], cur].max(active)
 
     use_visited = state.visited is not None
-    fused = (hop_backend == "pallas" and use_visited
-             and _fused_hop_eligible(vectors, metric))
-    if fused:
+    if hop_backend == "pallas":
+        if not use_visited:
+            raise ValueError("hop_backend='pallas' (fused hop) requires the "
+                             "visited filter: pass visited_size > 0")
+        _check_fused_hop(vectors, metric)
         from repro.kernels.fused_hop import ops as fh_ops
 
         cand_ids, cand_d, nbr_out, evals_inc = fh_ops.fused_hop(

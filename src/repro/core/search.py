@@ -6,11 +6,11 @@ The actual search loop lives in :mod:`repro.core.beam` (see ARCHITECTURE.md,
 inside one ``jax.lax.while_loop``, where each hop gathers the ``E * d``
 neighbors of the ``expand_width`` closest unchecked beam entries, dedups
 them (beam broadcast, or the O(probes) visited filter of
-``core/visited.py``), scores them (``gather_dist`` Pallas kernel on TPU —
-or the whole hop fused into ``kernels/fused_hop``), and folds them into the
-distance-sorted beam with the fused ``beam_merge`` bitonic partial-merge
-kernel (bit-identical to, and cheaper than, the seed's full ``(B, L+d)``
-argsort per hop).
+``core/visited.py``), scores them (jnp by default; ``backend="pallas"``
+runs the ``gather_dist`` kernel, ``hop_backend="pallas"`` the
+``kernels/fused_hop`` path), and folds them into the distance-sorted beam
+with the ``beam_merge`` bitonic partial merge (bit-identical to, and
+cheaper than, the seed's full ``(B, L+d)`` argsort per hop).
 
 This module keeps the public query API: :func:`range_search` resolves the
 beam-width/hop-budget defaults and jits the engine program;

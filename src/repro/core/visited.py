@@ -25,9 +25,9 @@ at most as many distance evaluations as the seed semantics; the trajectory
 — and the ``evals`` counters — can therefore differ from the E=1 broadcast
 engine.  See ARCHITECTURE.md ("Multi-expansion beam layering").
 
-The probe-position formula is shared verbatim with the ``fused_hop`` Pallas
-kernel (which performs the membership test in VMEM); keep them in sync by
-importing :func:`probe_positions` rather than re-deriving the hash.
+Every membership test, the ``fused_hop`` path's included, goes through
+:func:`contains`, so the probe-position formula lives only in
+:func:`probe_positions`.
 """
 from __future__ import annotations
 
@@ -120,9 +120,8 @@ def first_occurrence_mask(ids: Array, valid: Array) -> Array:
     sentinels so they never alias each other or real ids.
 
     This is THE intra-block dedup of the multi-expansion hop — shared by
-    the engine's jnp paths and the ``fused_hop`` oracle so they stay
-    bit-identical (the Pallas kernel reproduces it sequentially via its
-    ``seen`` scratch row)."""
+    the engine's jnp paths and the ``fused_hop`` oracle (whose filter half
+    the Pallas hop reuses) so they stay bit-identical."""
     import numpy as np
 
     C = ids.shape[1]

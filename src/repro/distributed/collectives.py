@@ -13,7 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 Array = jax.Array
 

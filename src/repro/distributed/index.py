@@ -24,9 +24,9 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import set_mesh, shard_map
+from jax import set_mesh, shard_map
 
 from repro.core import beam
 from repro.core.build import DEGIndex, DEGParams
@@ -161,6 +161,14 @@ def make_sharded_search(mesh: Mesh, *, k: int, eps: float = 0.1,
     return f
 
 
+@functools.lru_cache(maxsize=64)
+def _jitted_search(mesh: Mesh, **config) -> Callable:
+    """One jitted sharded-search program per (mesh, configuration); jax
+    then compiles it once per operand shape, so repeated ``search`` calls
+    reuse the compiled program instead of retracing a fresh closure."""
+    return jax.jit(make_sharded_search(mesh, **config))
+
+
 def _exact_rerank_owned(vecs, queries, ids, *, k, metric, n_shards, shard,
                         shard_axis):
     """Exact rerank of merged global ids inside shard_map: each shard
@@ -267,13 +275,31 @@ class ShardedDEG:
                 "exact_bytes": exact, "store_bytes": b,
                 "ratio": exact / b if b else 0.0}
 
+    def place(self, mesh: Mesh) -> "ShardedDEG":
+        """Put every stacked shard array on ``mesh``, split along its
+        leading shard dimension over the ``"model"`` axis (shard s on the
+        devices of mesh column s), so no one device holds them all.
+        Arrays already placed so are left as they are."""
+        def put(x):
+            if x is None:
+                return None
+            spec = P("model", *([None] * (x.ndim - 1)))
+            return jax.device_put(x, NamedSharding(mesh, spec))
+
+        for name in ("adjacency", "vectors", "n", "seeds", "codes",
+                     "scales", "codebooks"):
+            setattr(self, name, put(getattr(self, name)))
+        return self
+
     def search(self, mesh: Mesh, queries: np.ndarray, k: int,
                eps: float = 0.1, batch_axes="data",
                rerank_k: int = 0, expand_width: Optional[int] = None,
                visited_size: Optional[int] = None,
-               hop_backend: Optional[str] = None) -> tuple:
-        f = make_sharded_search(
-            mesh, k=k, eps=eps, metric=self.params.metric,
+               hop_backend: Optional[str] = None,
+               beam_width: Optional[int] = None) -> tuple:
+        f = _jitted_search(
+            mesh, k=k, eps=float(eps), beam_width=beam_width,
+            metric=self.params.metric,
             batch_axes=batch_axes, codec=self.codec, rerank_k=rerank_k,
             expand_width=(self.params.expand_width if expand_width is None
                           else expand_width),
@@ -281,6 +307,7 @@ class ShardedDEG:
                           else visited_size),
             hop_backend=(self.params.hop_backend if hop_backend is None
                          else hop_backend))
+        self.place(mesh)
         args = [self.adjacency, self.vectors]
         if self.codec != "float32":
             args += [self.codes, self.scales]
@@ -288,7 +315,7 @@ class ShardedDEG:
                 args += [self.codebooks]
         args += [self.n, self.seeds, jnp.asarray(queries)]
         with set_mesh(mesh):
-            ids, dists = jax.jit(f)(*args)
+            ids, dists = f(*args)
         return np.asarray(ids), np.asarray(dists)
 
     def refine(self, iterations: int, seed: Optional[int] = None) -> int:
@@ -340,7 +367,9 @@ def build_sharded_deg(vectors: np.ndarray, n_shards: int,
                       refine_iterations: int = 0,
                       codec: str = "float32") -> ShardedDEG:
     """Round-robin partition + per-shard incremental DEG build.
-    ``codec`` != "float32" attaches post-training quantized shard stores."""
+    ``codec`` != "float32" attaches post-training quantized shard stores;
+    :meth:`ShardedDEG.place` (which ``search`` calls) spreads the stacked
+    arrays over a mesh."""
     params = params or DEGParams()
     vectors = np.asarray(vectors, dtype=np.float32)
     N, m = vectors.shape

@@ -36,7 +36,7 @@ def _kernel(ids_ref, tab_ref, w_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bag_lookup_pallas(table: jax.Array, ids: jax.Array, weights: jax.Array, *,
-                      interpret: bool = True):
+                      interpret: bool):
     """table (V, E), ids (B, F) int32, weights (B, F) -> (B, E) weighted sum."""
     V, E = table.shape
     B, F = ids.shape

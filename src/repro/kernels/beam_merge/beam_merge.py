@@ -22,11 +22,13 @@ that coincides exactly with a stable argsort of the concatenation — the
 kernel is bit-identical to the seed merge, not merely equivalent up to
 ties.  The same property makes the network deterministic on all backends.
 
-The compare-exchange helpers are plain jnp on ``(..., T)`` arrays: the
-Pallas kernel body calls them on VMEM-resident blocks, and
-``ops.beam_merge(backend="jnp")`` calls them directly as the XLA fast path
-(the form the jitted search loop uses on CPU/GPU, and the baseline the
-microbenchmark compares against argsort).
+Two forms of the same network.  ``merge_beam_candidates`` exchanges
+partner lanes through a ``(..., c, 2, j)`` reshape; it is the XLA path
+``ops.beam_merge(backend="jnp")`` (the jitted search loop's default, and
+the baseline the microbenchmark compares against argsort).  Mosaic
+refuses that reshape, so the Pallas kernel runs ``_rotate_stage``, which
+fetches each lane's partner by lane rotation, on rows laid out by
+``_layout`` in XLA.  Both compute the same total order.
 
 Payload layout: distances f32 + rank i32 + three payload channels
 (vertex id i32, checked flag, excluded flag).  Flags travel as int32 inside
@@ -176,37 +178,106 @@ def merge_beam_candidates(beam_dists, beam_payload, cand_dists, cand_payload,
         x[..., :out_width] for x in merged[2:])
 
 
-def _kernel(bd_ref, bi_ref, bc_ref, bx_ref, cd_ref, ci_ref, cc_ref, cx_ref,
-            od_ref, oi_ref, oc_ref, ox_ref):
-    out = merge_beam_candidates(
-        bd_ref[...], (bi_ref[...], bc_ref[...], bx_ref[...]),
-        cd_ref[...], (ci_ref[...], cc_ref[...], cx_ref[...]),
-        presort="network")       # sort primitives don't lower in Pallas TPU
-    od_ref[...], oi_ref[...], oc_ref[...], ox_ref[...] = out
+def _rotate_stage(fields, j: int, desc, active=None):
+    """One compare-exchange stage at stride ``j`` over the whole last axis,
+    each lane fetching its partner (lane ^ j) by two lane rotations — the
+    form Mosaic lowers (it refuses ``_cmp_swap``'s ``(..., c, 2, j)``
+    reshape).  ``desc`` flips the direction per lane; lanes outside
+    ``active`` keep their values."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, fields[0].shape,
+                                    fields[0].ndim - 1)
+    lo = (lane & j) == 0
+
+    def partner(x):
+        return jnp.where(lo, jnp.roll(x, -j, axis=-1),
+                         jnp.roll(x, j, axis=-1))
+
+    pd, pr = partner(fields[0]), partner(fields[1])
+    # (dist, rank) lexicographic: does the partner belong before this lane?
+    before = (pd < fields[0]) | ((pd == fields[0]) & (pr < fields[1]))
+    swap = (before != ~lo) != desc         # lo lanes take an earlier
+    if active is not None:                 # partner, hi lanes a later one
+        swap &= active
+    return ((jnp.where(swap, pd, fields[0]), jnp.where(swap, pr, fields[1]))
+            + tuple(jnp.where(swap, partner(x), x) for x in fields[2:]))
+
+
+def _kernel(d_ref, r_ref, i_ref, c_ref, x_ref, od_ref, oi_ref, oc_ref,
+            ox_ref, *, dp: int):
+    """Rows hold ``[beam asc | +inf pads | candidates]`` (``_layout``):
+    sort the last ``dp`` lanes descending, then one bitonic merge."""
+    fields = tuple(ref[...] for ref in (d_ref, r_ref, i_ref, c_ref, x_ref))
+    T = fields[0].shape[-1]
+    c = jax.lax.broadcasted_iota(jnp.int32, fields[0].shape, 1) - (T - dp)
+    k = 2
+    while k <= dp:             # every comparator flipped: descending
+        j = k // 2
+        while j >= 1:
+            fields = _rotate_stage(fields, j, (c & k) == 0, active=c >= 0)
+            j //= 2
+        k *= 2
+    j = T // 2
+    while j >= 1:
+        fields = _rotate_stage(fields, j, False)
+        j //= 2
+    L = od_ref.shape[-1]
+    od_ref[...], oi_ref[...], oc_ref[...], ox_ref[...] = (
+        x[:, :L] for x in (fields[0],) + fields[2:])
+
+
+def _layout(beam_dists, beam_payload, cand_dists, cand_payload):
+    """The kernel's row layout: ``[beam | +inf pads | candidates | +inf]``
+    with ``(dist, rank)`` keys ranked as in ``merge_beam_candidates``
+    (beam 0..L-1, candidates L.., pads after everything).  Returns
+    (fields, dp)."""
+    B, L = beam_dists.shape
+    d = cand_dists.shape[1]
+    dp = _next_pow2(d)
+    T = _next_pow2(L + dp)
+    mid = T - L - dp
+
+    def lanes(n):
+        return _lane_iota((B,), n)
+
+    dists = jnp.concatenate(
+        [beam_dists, jnp.full((B, mid), _INF, beam_dists.dtype), cand_dists,
+         jnp.full((B, dp - d), _INF, cand_dists.dtype)], axis=1)
+    ranks = jnp.concatenate([lanes(L), T + lanes(mid), L + lanes(dp)], axis=1)
+    payload = tuple(
+        jnp.concatenate([bp, jnp.zeros((B, mid), bp.dtype), cp,
+                         jnp.zeros((B, dp - d), cp.dtype)], axis=1)
+        for bp, cp in zip(beam_payload, cand_payload))
+    return (dists, ranks) + payload, dp
+
+
+def _lane_iota(lead, n: int):
+    """int32 positions 0..n-1 along a new last axis of shape (*lead, n)."""
+    return jax.lax.broadcasted_iota(jnp.int32, (*lead, n), len(lead))
 
 
 @functools.partial(jax.jit, static_argnames=("tb", "interpret"))
 def beam_merge_pallas(beam_dists, beam_ids, beam_chk, beam_exc,
                       cand_dists, cand_ids, cand_chk, cand_exc,
-                      *, tb: int = 8, interpret: bool = True):
+                      *, tb: int = 8, interpret: bool):
     """Pallas dispatch: (B, L) beam + (B, d) candidates -> merged (B, L).
 
     Flag channels are int32.  B must be a multiple of ``tb`` (ops.py pads).
     The whole (tb, T<=2*(L+d)) working set lives in VMEM: at production
-    shapes (L<=512, d<=32, tb=8) that is ~170 KB across the seven channels —
-    far under budget, so the grid tiles the batch only.
+    shapes (L<=1024, d<=128, tb=8) that is well under 1 MB across the five
+    channels, so the grid tiles the batch only.
     """
     B, L = beam_dists.shape
-    d = cand_dists.shape[1]
     assert B % tb == 0, (B, tb)
-    grid = (B // tb,)
-    bspec = pl.BlockSpec((tb, L), lambda i: (i, 0))
-    cspec = pl.BlockSpec((tb, d), lambda i: (i, 0))
+    fields, dp = _layout(beam_dists, (beam_ids, beam_chk, beam_exc),
+                         cand_dists, (cand_ids, cand_chk, cand_exc))
+    T = fields[0].shape[1]
+    ispec = pl.BlockSpec((tb, T), lambda i: (i, 0))
+    ospec = pl.BlockSpec((tb, L), lambda i: (i, 0))
     return pl.pallas_call(
-        _kernel,
-        grid=grid,
-        in_specs=[bspec, bspec, bspec, bspec, cspec, cspec, cspec, cspec],
-        out_specs=[bspec, bspec, bspec, bspec],
+        functools.partial(_kernel, dp=dp),
+        grid=(B // tb,),
+        in_specs=[ispec] * 5,
+        out_specs=[ospec] * 4,
         out_shape=[
             jax.ShapeDtypeStruct((B, L), jnp.float32),
             jax.ShapeDtypeStruct((B, L), jnp.int32),
@@ -214,5 +285,4 @@ def beam_merge_pallas(beam_dists, beam_ids, beam_chk, beam_exc,
             jax.ShapeDtypeStruct((B, L), jnp.int32),
         ],
         interpret=interpret,
-    )(beam_dists, beam_ids, beam_chk, beam_exc,
-      cand_dists, cand_ids, cand_chk, cand_exc)
+    )(*fields)

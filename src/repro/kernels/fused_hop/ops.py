@@ -1,19 +1,23 @@
-"""Public dispatch for the fused multi-expansion hop.
+"""Public dispatch for the multi-expansion hop.
+
+One hop of the multi-expansion beam engine (``core/beam.py``,
+``expand_width=E``) is, per query lane: gather the adjacency rows of the E
+selected vertices, drop neighbors already visited or repeated within the
+hop, gather the surviving vector rows, score them against the query, and
+keep those inside the range radius, compacted to the front.
 
 Backends:
 
 * ``"jnp"``    — the pure-jnp oracle (``ref.fused_hop_ref``); also the
                  documentation of the hop semantics.
-* ``"pallas"`` — the fused kernel (interpret mode off-TPU).  Clamps
-                 out-of-range selection ids (INVALID lanes carry an
-                 explicit activity flag into SMEM), pads the feature dim
-                 to the 128-lane boundary (zero row x zero query padding
-                 contributes nothing to the distance), and normalizes the
-                 scalar operands to the (B, 1)/(1,) shapes the kernel's
-                 BlockSpecs expect.
+* ``"pallas"`` — the filter and compaction halves of the oracle around the
+                 ``gather_dist`` kernel: the surviving rows are copied
+                 HBM->VMEM and scored there, and filtered neighbors cost
+                 no copy at all (their ids reach the kernel negative).
+                 Ids, counts and the compaction order are the oracle's;
+                 distances agree to rounding.
 
-A ``visited=None`` call runs without the filter: the kernel receives a
-one-slot all-INVALID dummy table whose whole-row compare never hits.
+A ``visited=None`` call runs without the visited filter.
 """
 from __future__ import annotations
 
@@ -22,10 +26,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.graph import INVALID
+from repro.kernels import row_gather
+from repro.kernels.gather_dist.gather_dist import gather_dist_pallas
+from repro.kernels.gather_dist.ops import padded_queries
 
-from .fused_hop import fused_hop_pallas
-from .ref import fused_hop_ref
+from .ref import compact_hop, fused_hop_ref, hop_candidates
 
 
 def _default_interpret() -> bool:
@@ -40,38 +45,21 @@ def fused_hop(adjacency: jax.Array, vectors: jax.Array, sel_ids: jax.Array,
               squared: bool = False, backend: str = "jnp",
               interpret: bool | None = None):
     """One multi-expansion hop for B lanes — see ``ref.fused_hop_ref`` for
-    the argument/return contract (both backends are exact-parity)."""
+    the argument/return contract."""
+    dmax = jnp.asarray(dmax, jnp.float32)
     if backend == "jnp":
-        return fused_hop_ref(adjacency, vectors, sel_ids, queries,
-                             jnp.asarray(dmax, jnp.float32), visited,
-                             n_valid=n_valid, squared=squared)
+        return fused_hop_ref(adjacency, vectors, sel_ids, queries, dmax,
+                             visited, n_valid=n_valid, squared=squared)
     if backend != "pallas":
         raise ValueError(f"unknown fused_hop backend {backend!r}")
     if interpret is None:
         interpret = _default_interpret()
-    N, d = adjacency.shape
-    B, E = sel_ids.shape
-    m = vectors.shape[1]
-    pad_m = (-m) % 128
-    # bf16 rows stay bf16 on the HBM->VMEM DMA path (same policy as
-    # gather_dist); the kernel accumulates distances in f32 regardless.
-    # At aligned production dims (m % 128 == 0, f32/bf16 store) both
-    # branches below are no-ops, so the store is passed through untouched
-    # — only an unaligned store pays a loop-invariant pad+copy per jitted
-    # search program.
-    dt = vectors.dtype if vectors.dtype == jnp.bfloat16 else jnp.float32
-    v = vectors if vectors.dtype == dt else vectors.astype(dt)
-    q = queries if queries.dtype == dt else queries.astype(dt)
-    if pad_m:
-        v = jnp.pad(v, ((0, 0), (0, pad_m)))
-        q = jnp.pad(q, ((0, 0), (0, pad_m)))
-    act = (sel_ids != INVALID).astype(jnp.int32)
-    safe_sel = jnp.clip(sel_ids, 0, N - 1).astype(jnp.int32)
-    vis = (visited if visited is not None
-           else jnp.full((B, 1), INVALID, jnp.int32))
-    cand_ids, cand_d, nbr_ids, evals = fused_hop_pallas(
-        adjacency, v, safe_sel, act, q,
-        jnp.asarray(dmax, jnp.float32).reshape(B, 1), vis,
-        jnp.asarray(n_valid, jnp.int32).reshape(1,),
-        squared=squared, interpret=interpret)
-    return cand_ids, cand_d, nbr_ids, evals[:, 0]
+    flat, vmask, scored = hop_candidates(adjacency, sel_ids, visited,
+                                         n_valid=n_valid)
+    chunks = row_gather.lane_chunks(vectors)
+    qt = jnp.bfloat16 if vectors.dtype == jnp.bfloat16 else jnp.float32
+    q = padded_queries(queries, row_gather.LANES * len(chunks), qt)
+    ids = jnp.where(scored, flat, -1).astype(jnp.int32)
+    nd = gather_dist_pallas(chunks, ids, q, squared=squared,
+                            interpret=interpret)
+    return compact_hop(flat, vmask, scored, nd, dmax)

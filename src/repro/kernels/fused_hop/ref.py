@@ -1,10 +1,10 @@
-"""Pure-jnp oracle for the fused multi-expansion hop kernel.
+"""Pure-jnp oracle for the multi-expansion hop.
 
-This is the batch formulation of exactly what the Pallas kernel computes
-sequentially per lane, and (by construction) exactly what the beam
-engine's jnp hop path computes when the visited filter is active — so the
-kernel, this oracle, and the engine's composed path are mutually
-bit-identical.  See ``fused_hop.py`` for the op-by-op correspondence.
+This is exactly what the beam engine's jnp hop path computes when the
+visited filter is active, so the oracle and the engine's composed path
+are bit-identical.  The Pallas path (``ops.py``) shares the filter half
+(:func:`hop_candidates`) and the compaction half (:func:`compact_hop`)
+and replaces only the scoring in between with the ``gather_dist`` kernel.
 """
 from __future__ import annotations
 
@@ -42,6 +42,22 @@ def fused_hop_ref(adjacency: jax.Array, vectors: jax.Array,
         (for the caller's visited-set insertion);
       evals (B,) int32 — distance evaluations performed (post-filter).
     """
+    flat, vmask, scored = hop_candidates(adjacency, sel_ids, visited,
+                                         n_valid=n_valid, n_probes=n_probes)
+    safe = jnp.where(scored, flat, 0)
+    g = vectors[safe].astype(jnp.float32)                    # (B, Ed, m)
+    diff = g - queries.astype(jnp.float32)[:, None, :]
+    d2 = jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0)
+    nd = d2 if squared else jnp.sqrt(d2)
+    return compact_hop(flat, vmask, scored, nd, dmax)
+
+
+def hop_candidates(adjacency, sel_ids, visited, *, n_valid,
+                   n_probes: int = DEFAULT_PROBES):
+    """The hop's filter half: gather the adjacency rows of the selections
+    and decide which neighbors get scored.  Returns (flat (B, E*d) raw
+    neighbor ids, vmask (B, E*d) valid, scored (B, E*d) valid & first
+    occurrence & not visited)."""
     B, E = sel_ids.shape
     d = adjacency.shape[1]
     Ed = E * d
@@ -56,16 +72,15 @@ def fused_hop_ref(adjacency: jax.Array, vectors: jax.Array,
     scored = vmask & first_occurrence_mask(flat, vmask)
     if visited is not None:
         scored &= ~contains(visited, flat, n_probes=n_probes)
+    return flat, vmask, scored
 
-    safe = jnp.where(scored, flat, 0)
-    g = vectors[safe].astype(jnp.float32)                    # (B, Ed, m)
-    diff = g - queries.astype(jnp.float32)[:, None, :]
-    d2 = jnp.maximum(jnp.sum(diff * diff, axis=-1), 0.0)
-    nd = d2 if squared else jnp.sqrt(d2)
+
+def compact_hop(flat, vmask, scored, nd, dmax):
+    """The hop's output half: radius keep test and stable compaction of the
+    scored distances ``nd`` (B, E*d) — kept candidates first, discovery
+    order preserved.  Returns the four hop outputs."""
     nd = jnp.where(scored, nd, jnp.inf)
     keep = scored & (nd <= dmax[:, None])
-
-    # stable compaction: kept candidates first, discovery order preserved
     order = jnp.argsort(~keep, axis=1, stable=True)
     cand_ids = jnp.take_along_axis(jnp.where(keep, flat, INVALID), order,
                                    axis=1)

@@ -1,12 +1,14 @@
 """Public wrapper for the gather+distance kernel: clamps out-of-range ids
-(INVALID = -1 slots are masked by the caller), pads the feature dim to the
-128-lane boundary."""
+(INVALID = -1 slots are masked by the caller) and lays the table out as
+the 128-lane 32-bit chunks the kernel's row copies need."""
 from __future__ import annotations
 
 import functools
 
 import jax
 import jax.numpy as jnp
+
+from repro.kernels import row_gather
 
 from .gather_dist import gather_dist_pallas
 
@@ -15,26 +17,22 @@ def _default_interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
+def padded_queries(queries: jax.Array, m_pad: int, dtype=jnp.float32):
+    """(B, m) queries -> (B, m_pad) f32, rounded through ``dtype`` first
+    (a bf16 store scores its queries in bf16, as it always has)."""
+    q = queries.astype(dtype).astype(jnp.float32)
+    return jnp.pad(q, ((0, 0), (0, m_pad - q.shape[1])))
+
+
 @functools.partial(jax.jit, static_argnames=("squared", "interpret"))
 def gather_dist(vectors: jax.Array, ids: jax.Array, queries: jax.Array, *,
                 squared: bool = False, interpret: bool | None = None):
     if interpret is None:
         interpret = _default_interpret()
-    N, m = vectors.shape
-    pad_m = (-m) % 128
-    # Half-width vectors (bf16 AND f16) stay half-width on the HBM->VMEM
-    # path — halving the gather traffic that dominates the DEG search
-    # roofline (§Perf DEG it. 2).  Upcasting the fp16 store here used to
-    # materialize a full-size f32 copy every hop, defeating the 2x codec;
-    # the kernel upcasts per-tile instead.  Queries stay f32 for f16
-    # stores (f16->f32 is exact, so results are bit-identical to the old
-    # upcast-everything path); bf16 keeps its historical
-    # query-in-store-dtype behavior.
-    dt = (vectors.dtype
-          if vectors.dtype in (jnp.bfloat16, jnp.float16) else jnp.float32)
-    qt = dt if dt == jnp.bfloat16 else jnp.float32
-    v = jnp.pad(vectors.astype(dt), ((0, 0), (0, pad_m)))
-    q = jnp.pad(queries.astype(qt), ((0, 0), (0, pad_m)))
+    N = vectors.shape[0]
+    chunks = row_gather.lane_chunks(vectors)
+    qt = jnp.bfloat16 if vectors.dtype == jnp.bfloat16 else jnp.float32
+    q = padded_queries(queries, row_gather.LANES * len(chunks), qt)
     safe_ids = jnp.clip(ids, 0, N - 1).astype(jnp.int32)
-    return gather_dist_pallas(v, safe_ids, q, squared=squared,
+    return gather_dist_pallas(chunks, safe_ids, q, squared=squared,
                               interpret=interpret)

@@ -92,7 +92,7 @@ def _kernel(q_ref, x_ref, od_ref, oi_ref, sd_ref, si_ref, *, k: int,
 )
 def l2_topk_pallas(queries: jax.Array, base: jax.Array, k: int, *,
                    tb: int = 8, tn: int = 512, squared: bool = False,
-                   interpret: bool = True):
+                   interpret: bool):
     """queries (B, m), base (N, m) -> (dists (B, k), ids (B, k)).
 
     B must be a multiple of tb and N of tn (ops.py pads)."""

@@ -65,5 +65,5 @@ def pq_adc(codes: jax.Array, codebooks: jax.Array, ids: jax.Array,
     N = codes.shape[0]
     c, cb2, sel, q = padded_operands(codes, codebooks, queries)
     safe_ids = jnp.clip(ids, 0, N - 1).astype(jnp.int32)
-    return pq_adc_pallas(c, cb2, sel, safe_ids, q, squared=squared,
-                         interpret=interpret)
+    return pq_adc_pallas(c.astype(jnp.int32), cb2, sel, safe_ids, q,
+                         squared=squared, interpret=interpret)

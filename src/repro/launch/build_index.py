@@ -33,6 +33,9 @@ def main() -> None:
     from repro.core.invariants import check_invariants
     from repro.core.metrics import recall_at_k
     from repro.data.synthetic import gaussian_mixture, planted_manifold
+    from repro.launch import compile_cache
+
+    print(f"compile cache: {compile_cache.enable()}")
 
     gen = gaussian_mixture if args.lid == "low" else planted_manifold
     vecs = gen(args.n + 500, args.dim, seed=args.seed)

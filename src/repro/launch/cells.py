@@ -70,7 +70,7 @@ class CellProgram:
             donate_argnums=self.donate)
 
     def lower(self, mesh: Mesh):
-        from repro.compat import set_mesh
+        from jax import set_mesh
 
         with set_mesh(mesh):
             return self.jitted(mesh).lower(*self.args)

@@ -27,6 +27,19 @@ def make_debug_mesh(*, multi_pod: bool = False):
     return jax.make_mesh(shape, axes)
 
 
+def make_shard_mesh(n_shards: int):
+    """``("data", "model") = (1, n_shards)`` over the first ``n_shards`` of
+    ``jax.devices()``: one index shard per device, queries replicated —
+    the sharded-DEG layout on one host (e.g. the four chips of a v5e
+    2x2)."""
+    devices = jax.devices()
+    if len(devices) < n_shards:
+        raise ValueError(f"{n_shards} shards need {n_shards} devices, "
+                         f"have {len(devices)}")
+    return jax.make_mesh((1, n_shards), ("data", "model"),
+                         devices=devices[:n_shards])
+
+
 def batch_axes(mesh) -> tuple:
     """The data-parallel axes of a mesh (pod axis included when present)."""
     return tuple(a for a in ("pod", "data") if a in mesh.axis_names)

@@ -78,7 +78,11 @@ def _load_index(path):
     return idx
 
 
-def main() -> None:
+def main() -> int:
+    """Run the launcher; returns the process exit code (nonzero when the
+    async run served nothing, when requests crashed without a ``--faults``
+    plan that provoked it, or when background refinement failed on a graph
+    no ``--inject-corruption`` damaged)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--index", default=None,
                     help="warm-start from a persist snapshot (.npz from "
@@ -199,6 +203,7 @@ def main() -> None:
 
     from repro import obs
     from repro.core.build import DEGIndex, DEGParams, build_deg
+    from repro.launch import compile_cache
     from repro.core.distances import exact_knn_batched
     from repro.core.metrics import recall_at_k
     from repro.data.synthetic import make_dataset
@@ -208,6 +213,7 @@ def main() -> None:
     from repro.serving.async_engine import AsyncQueryEngine
     from repro.serving.engine import QueryEngine
 
+    print(f"compile cache: {compile_cache.enable()}")
     if args.faults:
         plan = FaultPlan.parse(args.faults, seed=args.fault_seed)
         install_faults(plan)
@@ -312,6 +318,7 @@ def main() -> None:
                     except Exception:
                         # refinement may race injected corruption; the
                         # scrubber heals the graph and the next tick works
+                        # (without injected damage, errors fail the run)
                         refine_stats["errors"] += 1
                     if refine_stop.wait(0.05):
                         return
@@ -425,7 +432,18 @@ def main() -> None:
             idx.save(args.save_index)
             print(f"saved index snapshot to {args.save_index} "
                   f"(n={idx.n}; warm-start with --index)")
-        return
+        failed = []
+        if not futs:
+            failed.append("served 0 queries")
+        if crashed and not args.faults:
+            failed.append(f"{crashed} requests crashed with no fault plan")
+        if refine_stats["errors"] and not args.inject_corruption:
+            failed.append(f"{refine_stats['errors']} refine ticks failed "
+                          "on an undamaged graph")
+        if failed:
+            print("FAILED: " + "; ".join(failed))
+            return 1
+        return 0
 
     engine = QueryEngine(idx, k=args.k, eps=args.eps, max_batch=args.batch,
                          refine_budget=args.refine_budget,
@@ -484,7 +502,10 @@ def main() -> None:
         engine.save(args.save_index)
         print(f"saved index snapshot to {args.save_index} "
               f"(n={idx.n}; warm-start with --index)")
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    import sys
+
+    sys.exit(main())

@@ -185,7 +185,7 @@ def make_sharded_loss(cfg: EGNNConfig, mesh, shard_axes) -> "Callable":
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     axes = tuple(shard_axes)
 

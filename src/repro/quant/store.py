@@ -16,8 +16,8 @@ Four views behind one interface:
 * ``fp16`` — half-precision rows, gathered at half width and upcast
   per-tile inside the kernel.
 * ``sq8`` — int8 codes + per-dimension scale; the hot gather path runs the
-  fused ``kernels/gather_dist_q`` gather→dequant→distance kernel (Pallas on
-  TPU, jnp elsewhere).
+  fused ``kernels/gather_dist_q`` gather→dequant→distance kernel (with
+  ``backend="pallas"``).
 * ``pq`` — product-quantized uint8 codes (one byte per subspace) + shared
   ``(m_sub, 256, dsub)`` codebooks; the hot path runs the fused
   ``kernels/pq_adc`` LUT-ADC kernel, which never decodes — for l2 the
@@ -90,13 +90,17 @@ class VectorStore:
         routes l2 to the fused gather kernels (``gather_dist`` for float
         codecs, ``gather_dist_q`` for sq8, ``pq_adc`` for pq — the last
         never decodes: it scans gathered code bytes against a per-query
-        LUT built once in VMEM); everything else takes the jnp
-        gather+pair path, which for float32 is the exact pre-store
-        program.
+        LUT built once in VMEM) and raises for any other metric;
+        ``backend='jnp'`` takes the gather+pair path, which for float32 is
+        the exact pre-store program.
         """
         from repro.core.distances import get_metric
 
-        if backend == "pallas" and metric_name == "l2":
+        if backend == "pallas":
+            if metric_name != "l2":
+                raise ValueError(
+                    "the pallas distance kernels are l2 only, got metric "
+                    f"{metric_name!r} (use backend='jnp')")
             if self.codec == "sq8":
                 from repro.kernels.gather_dist_q import ops as gdq_ops
 
@@ -110,6 +114,8 @@ class VectorStore:
             from repro.kernels.gather_dist import ops as gd_ops
 
             return gd_ops.gather_dist(self.data, nbr_ids, queries)
+        if backend != "jnp":
+            raise ValueError(f"unknown distance backend {backend!r}")
         metric = get_metric(metric_name)
         nvecs = self.decode(nbr_ids)                       # (B, d, m)
         return metric.pair(queries[:, None, :], nvecs)     # (B, d)

@@ -88,8 +88,10 @@ def build_ladder(base: ProgramConfig, degree: int,
     rungs.append(LadderRung("hop-cap", slim, hop_budget=budget))
     if base.codec == "float32":
         rerank = 2 * k if policy.last_rung_rerank == "2k" else None
+        # a compressed store has no fused hop kernel: the rung runs the
+        # jnp hop explicitly rather than asking for pallas and raising
         quant = dataclasses.replace(slim, codec=policy.last_rung_codec,
-                                    rerank_k=rerank)
+                                    rerank_k=rerank, hop_backend="jnp")
         rungs.append(LadderRung("sq8", quant, hop_budget=budget))
     return rungs[: policy.max_rung + 1]
 

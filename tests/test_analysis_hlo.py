@@ -85,7 +85,7 @@ def test_collective_bytes_counted():
         pytest.skip("needs >=2 devices")
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import set_mesh, shard_map
+    from jax import set_mesh, shard_map
 
     mesh = jax.make_mesh((jax.device_count(),), ("x",))
     x = jnp.ones((jax.device_count() * 4, 8), jnp.float32)

@@ -1,12 +1,15 @@
 """Differential recall: the full engine-knob grid vs brute force.
 
-Every (codec, expand_width, hop_backend) combination of the query engine
+Every (codec, expand_width, kernels) combination of the query engine
 searches the same seeded index and is held to a pinned recall@10 floor
 against ``baselines/brute_force`` — a knob combination can't silently
 regress (e.g. a visited-filter bug that only bites the fused hop, or a
-rerank path that only bites sq8).  The pallas hop runs in interpret mode
-off-TPU (``kernels/fused_hop/ops._default_interpret``), so the grid covers
-both hop programs everywhere.
+rerank path that only bites sq8).  ``kernels="pallas"`` selects each
+codec's Pallas route: the fused hop for the exact float32 store, the
+distance kernels (``gather_dist`` / ``gather_dist_q`` / ``pq_adc``) for
+the compressed ones, which the fused hop refuses.  Off-TPU the kernels run
+in interpret mode (``_default_interpret`` of each ``ops``), so the grid
+covers both programs everywhere.
 """
 from __future__ import annotations
 
@@ -43,28 +46,35 @@ def corpus():
     return idx, queries, np.asarray(gt_ids)
 
 
-@pytest.mark.parametrize("codec,expand_width,hop_backend", GRID)
-def test_recall_floor(corpus, codec, expand_width, hop_backend):
+def _search(idx, queries, codec, expand_width, kernels):
+    pallas = kernels == "pallas"
+    exact = codec == "float32"
+    return idx.search(queries, k=K, eps=0.2,
+                      quantized=None if exact else codec,
+                      expand_width=expand_width,
+                      hop_backend="pallas" if pallas and exact else "jnp",
+                      backend="pallas" if pallas and not exact else "jnp")
+
+
+@pytest.mark.parametrize("codec,expand_width,kernels", GRID)
+def test_recall_floor(corpus, codec, expand_width, kernels):
     idx, queries, gt = corpus
-    res = idx.search(queries, k=K, eps=0.2,
-                     quantized=None if codec == "float32" else codec,
-                     expand_width=expand_width, hop_backend=hop_backend)
+    res = _search(idx, queries, codec, expand_width, kernels)
     rec = recall_at_k(np.asarray(res.ids), gt)
     assert rec >= FLOORS[codec], (
         f"recall@{K}={rec:.4f} under floor {FLOORS[codec]} for "
-        f"codec={codec} E={expand_width} hop={hop_backend}")
+        f"codec={codec} E={expand_width} kernels={kernels}")
 
 
 def test_grid_agrees_with_itself(corpus):
-    """E/hop are engine reshapes, not semantics: within one codec, every
-    (E, hop) combination must return the same result *set* quality — their
-    recalls may not diverge by more than one result out of k."""
+    """E/kernels are engine reshapes, not semantics: within one codec,
+    every (E, kernels) combination must return the same result *set*
+    quality — their recalls may not diverge by more than one result out
+    of k."""
     idx, queries, gt = corpus
     for codec in FLOORS:
         recs = []
-        for E, hop in itertools.product([1, 2], ["jnp", "pallas"]):
-            res = idx.search(queries, k=K, eps=0.2,
-                             quantized=None if codec == "float32" else codec,
-                             expand_width=E, hop_backend=hop)
+        for E, kernels in itertools.product([1, 2], ["jnp", "pallas"]):
+            res = _search(idx, queries, codec, E, kernels)
             recs.append(recall_at_k(np.asarray(res.ids), gt))
         assert max(recs) - min(recs) <= 1.0 / K + 1e-9, (codec, recs)

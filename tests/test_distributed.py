@@ -25,7 +25,7 @@ if jax.device_count() < 8:
 
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.compat import set_mesh  # noqa: E402
+from jax import set_mesh  # noqa: E402
 from repro.core.build import DEGParams  # noqa: E402
 from repro.distributed.collectives import (  # noqa: E402
     compressed_psum, int8_compress, int8_decompress, make_sharded_lookup,
@@ -82,7 +82,7 @@ def test_compressed_psum_global_scale_agreement(mesh):
     must agree on ONE global scale before quantizing — so every device
     produces bitwise-identical output and the error is bounded by the
     *global* amax, not the per-shard ones."""
-    from repro.compat import shard_map
+    from jax import shard_map
 
     n_dev = 4
     rng = np.random.default_rng(11)
@@ -114,7 +114,7 @@ def test_compressed_grad_allreduce_tree(mesh):
     """make_compressed_grad_allreduce: tree-structured int8 mean-allreduce
     matches the exact per-leaf mean within the global-scale bound and
     preserves leaf dtypes."""
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.distributed.collectives import make_compressed_grad_allreduce
 
     n_dev = 4
@@ -143,7 +143,7 @@ def test_compressed_grad_allreduce_tree(mesh):
 
 
 def test_compressed_psum_approximates_sum(mesh):
-    from repro.compat import shard_map
+    from jax import shard_map
 
     n_dev = 4                       # the 2x2 debug mesh
     rng = np.random.default_rng(3)
