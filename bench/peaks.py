@@ -1,0 +1,26 @@
+"""Published peaks of each accelerator, keyed by ``device_kind`` as JAX
+reports it.  A device that is not in the table is an error, never a
+default.
+
+Source: Google Cloud documentation, "TPU v5e" (per chip: 197 TFLOP/s
+bfloat16, 393 TOP/s int8, 16 GB HBM2 at 819 GB/s).
+"""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12,
+        "int8_ops": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "source": "Google Cloud TPU v5e documentation",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peaks for device kind {device_kind!r}; "
+                       f"bench/peaks.py has {sorted(PEAKS)}") from None
