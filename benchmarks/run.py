@@ -1,9 +1,8 @@
 """Benchmark orchestrator: ``PYTHONPATH=src python -m benchmarks.run``.
 
 One section per paper table/figure (Sec. 6-7 + Appendix F/G), plus the
-kernel structural benchmarks and the §Roofline aggregation of the dry-run
-artifacts.  Emits a CSV (reports/bench.csv) and prints one line per
-measurement.  ``--quick`` shrinks every dataset ~4x for smoke use.
+kernel structural benchmarks.  Emits a CSV (reports/bench.csv) and prints
+one line per measurement.  ``--quick`` shrinks every dataset ~4x for smoke use.
 """
 from __future__ import annotations
 
@@ -26,7 +25,6 @@ SECTIONS = [
     ("quantized_store", "quantization"),
     ("search_pareto", "search_pareto"),
     ("serving_open_loop", "serving_load"),
-    ("roofline", "roofline_report"),
 ]
 
 QUICK_OVERRIDES = {

@@ -37,8 +37,7 @@ into a fresh registry, and the replayed request-latency p50/p99 and
 recall@10 must equal the live registry's / the harness's figures
 *exactly* — bench and prod share one measurement path, and the log is
 proven to carry it.  The final registry snapshot lands in
-``reports/serving_metrics.json`` (the roofline report's kernel-time
-attribution input).
+``reports/serving_metrics.json``.
 
 ``quick=True`` (the CI smoke gate) shrinks everything, pins the seed,
 runs one traced phase (timing-ratio gates are too flaky for shared
@@ -268,7 +267,7 @@ def run(n: int = 6000, n_query: int = 256, dim: int = 32, k: int = 10,
          replay_p50_ms=replayed.percentile(50),
          replay_p99_ms=replayed.percentile(99), replay_recall=log_rec)
 
-    # registry snapshot for the roofline report's serving attribution
+    # registry snapshot, for reading the counters offline
     metrics_path = os.path.join(reports, "serving_metrics.json")
     with open(metrics_path, "w") as f:
         f.write(registry.snapshot_json())

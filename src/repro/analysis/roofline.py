@@ -154,17 +154,8 @@ def recsys_model_flops(cfg, kind: str, batch: int,
 
 # ---------------------------------------------------------------------------
 # structural Pallas-kernel tiles: HBM bytes + flops per tile at BlockSpec
-# granularity — shared by benchmarks/kernels.py and the §Roofline report
+# granularity — read by benchmarks/kernels.py
 # ---------------------------------------------------------------------------
-# dims at the production-search cell scale (launch/cells.py DEG_CELLS):
-# degree 30, dim 128, beam 64, k_ext 60; int8 codes for the sq8 store.
-KERNEL_DIMS = {
-    "gather_dist": dict(d=30, m=128),
-    "gather_dist_q": dict(d=30, m=128),
-    "beam_merge": dict(L=64, d=30),
-    "mrng_occlusion": dict(K=60, d=30, m=128),
-    "fused_hop": dict(E=4, d=30, m=128, V=2048),
-}
 
 
 def kernel_tile_costs(name: str, **dims) -> dict:
@@ -214,40 +205,7 @@ def kernel_tile_costs(name: str, **dims) -> dict:
                               + E * d * m * 4
                               + (E * d * 2 + E * d + 1) * 4),
                 "flops": E * d * (2.0 * m + E * d + V + 2.0)}
-    raise ValueError(f"unknown kernel {name!r}; have {sorted(KERNEL_DIMS)}")
-
-
-def kernel_roofline(name: str, **dims) -> Roofline:
-    """Single-tile roofline of a Pallas kernel (no collectives)."""
-    c = kernel_tile_costs(name, **(dims or KERNEL_DIMS[name]))
-    return from_costs(c["flops"], c["hbm_bytes"], 0.0,
-                      model_flops=c["flops"])
-
-
-def attribute_kernel_time(total_s: float, tile_counts: dict) -> dict:
-    """Split a *measured* wall-time total across Pallas kernels in
-    proportion to their structural cost: weight(k) = tiles_k x the
-    single-tile roofline step time (max of the compute/memory terms).
-
-    This is the bridge between the serving telemetry (obs/ histograms
-    measure how long flushes took, but a jitted program is opaque) and
-    the structural model (which knows each kernel's relative expense but
-    not the wall clock): tile counts come from the engine's hop/eval
-    counters, the split from the model.  Returns
-    ``{kernel: {"tiles", "weight_s", "seconds", "fraction"}}``; fractions
-    sum to 1 when any weight is nonzero.
-    """
-    weights = {}
-    for name, tiles in tile_counts.items():
-        r = kernel_roofline(name, **KERNEL_DIMS[name])
-        weights[name] = float(tiles) * r.step_time
-    denom = sum(weights.values())
-    out = {}
-    for name, tiles in tile_counts.items():
-        frac = weights[name] / denom if denom > 0 else 0.0
-        out[name] = {"tiles": float(tiles), "weight_s": weights[name],
-                     "seconds": frac * total_s, "fraction": frac}
-    return out
+    raise ValueError(f"unknown kernel {name!r}")
 
 
 def deg_model_flops(meta: dict, avg_hops: float) -> float:

@@ -74,6 +74,10 @@ class BeamState:
     # (B, V) int32 open-addressing visited set (core/visited.py), or None
     # when the engine runs the seed beam-broadcast dedup instead
     visited: Optional[Array] = None
+    # () int32 — the while_loop's trip count, set by beam_search on its
+    # final state: every one of the B lanes runs each trip, so
+    # trips * B lane-trips against sum(hops) is the lock-step waste
+    trips: Optional[Array] = None
 
     @property
     def width(self) -> int:
@@ -362,7 +366,8 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
                 visited_size: int = 0,
                 hop_backend: str = "jnp",
                 hop_budget: Optional[Array] = None) -> BeamState:
-    """init -> while(expand) -> final BeamState.  Pure (un-jitted): callers
+    """init -> while(expand) -> final BeamState, its ``trips`` the loop's
+    trip count.  Pure (un-jitted): callers
     embed it in their own jitted programs (``range_search``, the sharded
     search step) so every layer reuses one implementation.
 
@@ -410,9 +415,9 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
         return (state, it + 1,
                 alive(state, k=k, eps=eps, hop_budget=hop_budget).any())
 
-    state, _, _ = jax.lax.while_loop(
+    state, trips, _ = jax.lax.while_loop(
         cond, body, (state0, jnp.int32(0), jnp.asarray(True)))
-    return state
+    return dataclasses.replace(state, trips=trips)
 
 
 # jitted standalone primitives (library surface for out-of-loop callers)

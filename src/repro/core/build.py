@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 from .distances import get_metric
 from .graph import (DEGraph, GraphBuilder, INVALID, complete_graph,
                     pow2_bucket)
@@ -133,7 +135,8 @@ class DEGIndex:
         # re-encode + re-calibrate from the live rows, never retrain)
         self._stores: dict = {}
         # per-stage wall time of _insert_wave (candidate search vs vertex
-        # extension) — benchmarks/build_cost.py reports both
+        # extension), from the deg.add.search / deg.add.extend spans —
+        # benchmarks/build_cost.py reports both
         self.build_stats = {"search_s": 0.0, "extend_s": 0.0, "vertices": 0}
         # optional obs.MetricsRegistry: when attached (launch/serve.py,
         # benches), insert waves and refine sweeps record their stage
@@ -322,12 +325,17 @@ class DEGIndex:
             return
         self._refine_chunk_counter += 1
         if self._refine_chunk_counter % self._publish_every_chunks == 0:
-            self.publish()
+            with span("deg.tick"):
+                self.publish()
 
     # -- insertion -----------------------------------------------------------
     @_locked
     def add(self, points: np.ndarray, wave_size: int = 1) -> None:
         """Insert points (Alg. 3). ``wave_size>1`` enables bulk build."""
+        with span("deg.add"):
+            self._add(points, wave_size)
+
+    def _add(self, points: np.ndarray, wave_size: int) -> None:
         points = np.asarray(points, dtype=np.float32)
         if points.ndim == 1:
             points = points[None]
@@ -362,69 +370,69 @@ class DEGIndex:
             # the applied waves
             self._wal_record("add", {"wave_size": int(w)},
                              {"points": points[i : i + w]})
-            self._insert_wave(points[i : i + w])
+            with span("deg.add.wave", wave=self._wave_counter):
+                self._insert_wave(points[i : i + w])
             i += w
 
     def _insert_wave(self, pts: np.ndarray) -> None:
-        from repro.obs import clock
-
         W = pts.shape[0]
         start = self.builder.n
         self.vectors[start : start + W] = pts
         self._put_rows(pts, start)
         # one batched candidate search for the whole wave (pre-wave graph),
         # through the same engine program as every other consumer
-        t0 = clock.now()
-        seeds = np.full((W, 1), self._entry_vertex(), dtype=np.int32)
-        res = self.search_batch(pts, seeds, k=self.params.k_ext,
-                                eps=self.params.eps_ext)
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        t1 = clock.now()
+        with span("deg.add.search", self.metrics, device=True,
+                  metric="build_wave_search") as s_search:
+            seeds = np.full((W, 1), self._entry_vertex(), dtype=np.int32)
+            res = self.search_batch(pts, seeds, k=self.params.k_ext,
+                                    eps=self.params.eps_ext)
+            ids = np.asarray(res.ids)
+            dists = np.asarray(res.dists)
         use_device = self.params.device_extend
         block = max(int(self.params.extend_block), 1) if use_device else W
-        for j0 in range(0, W, block):
-            j1 = min(j0 + block, W)
-            vs = [self.builder.add_vertex() for _ in range(j0, j1)]
-            assert vs[0] == start + j0
-            if use_device:
-                # Alg. 3 selection for a block of vertices in ONE device
-                # program against the freshly synced graph (the dirty-row
-                # scatter in device_graph picks up the previous block's
-                # edge swaps), then ONE vectorized application of every
-                # selection that survived intra-block conflicts
-                # (first-lane-wins, matching the host application order).
-                from .extend import extend_wave
+        # the extension stretch: device selection + its bulk application,
+        # with the host completions (deg.add.complete) nested inside
+        with span("deg.add.extend", self.metrics, device=use_device,
+                  metric="build_wave_extend") as s_extend:
+            for j0 in range(0, W, block):
+                j1 = min(j0 + block, W)
+                vs = [self.builder.add_vertex() for _ in range(j0, j1)]
+                assert vs[0] == start + j0
+                if use_device:
+                    # Alg. 3 selection for a block of vertices in ONE
+                    # device program against the freshly synced graph (the
+                    # dirty-row scatter in device_graph picks up the
+                    # previous block's edge swaps), then ONE vectorized
+                    # application of every selection that survived
+                    # intra-block conflicts (first-lane-wins, matching the
+                    # host application order).
+                    from .extend import extend_wave
 
-                sel_ids, sel_d, ok = extend_wave(
-                    self, pts[j0:j1], ids[j0:j1], dists[j0:j1], start + j0)
-                self._apply_extension_block(start + j0, sel_ids, sel_d, ok)
-            for j in range(j0, j1):
-                v = start + j
-                # warm start from the LIVE row: a host completion of an
-                # earlier lane may have stolen (or added) edges of this
-                # vertex since the bulk apply
-                live = self.builder.neighbors(v)
-                if len(live) == self.params.degree:
-                    new_edges = [int(x) for x in live]
-                else:
-                    new_edges = self._extend_vertex(
-                        v, pts[j], ids[j], dists[j],
-                        [int(x) for x in live],
-                        [float(x) for x in
-                         self.builder.neighbor_weights(v)])
-                self._post_insert(v, new_edges, ids[j])
-        t2 = clock.now()
-        self.build_stats["search_s"] += t1 - t0
-        self.build_stats["extend_s"] += t2 - t1
+                    sel_ids, sel_d, ok = extend_wave(
+                        self, pts[j0:j1], ids[j0:j1], dists[j0:j1],
+                        start + j0)
+                    self._apply_extension_block(start + j0, sel_ids, sel_d,
+                                                ok)
+                with span("deg.add.complete"):
+                    for j in range(j0, j1):
+                        v = start + j
+                        # warm start from the LIVE row: a host completion
+                        # of an earlier lane may have stolen (or added)
+                        # edges of this vertex since the bulk apply
+                        live = self.builder.neighbors(v)
+                        if len(live) == self.params.degree:
+                            new_edges = [int(x) for x in live]
+                        else:
+                            new_edges = self._extend_vertex(
+                                v, pts[j], ids[j], dists[j],
+                                [int(x) for x in live],
+                                [float(x) for x in
+                                 self.builder.neighbor_weights(v)])
+                        self._post_insert(v, new_edges, ids[j])
+        self.build_stats["search_s"] += s_search.seconds
+        self.build_stats["extend_s"] += s_extend.seconds
         self.build_stats["vertices"] += W
         if self.metrics is not None:
-            # wave-stage spans: same timestamps build_stats accumulates,
-            # but as histograms (per-wave distribution, not just totals)
-            self.metrics.histogram("build_wave_search_ms").observe(
-                (t1 - t0) * 1e3)
-            self.metrics.histogram("build_wave_extend_ms").observe(
-                (t2 - t1) * 1e3)
             self.metrics.counter("build_vertices_total").inc(W)
         self._checkpoint_tick()
 
@@ -608,6 +616,10 @@ class DEGIndex:
         ONE device call (optimize.refine_sweep), instead of a per-edge
         ``_search_from`` round-trip.  Host-side graph surgery is unchanged.
         Returns the number of improved edges."""
+        with span("deg.refine"):
+            return self._refine(iterations, seed)
+
+    def _refine(self, iterations: int, seed: Optional[int]) -> int:
         from .optimize import refine_sweep
 
         if self.builder is None or self.builder.n <= self.builder.degree + 1:
@@ -743,8 +755,9 @@ class DEGIndex:
                 # here could not be continued by replay.  Waves are safe
                 # (one record per wave).  Replay itself never writes.
                 and not self._wal_op_active and self._wal_replay is None):
-            self.save(str(self._ckpt_path).format(
-                waves=self._wave_counter, n=self.n))
+            with span("deg.tick"):
+                self.save(str(self._ckpt_path).format(
+                    waves=self._wave_counter, n=self.n))
 
     # -- queries --------------------------------------------------------------
     def search_batch(self, queries: np.ndarray,
@@ -849,9 +862,10 @@ class DEGIndex:
         s = np.full((1, 2), INVALID, dtype=np.int32)
         for j, sid in enumerate(list(seed_ids)[:2]):
             s[0, j] = sid
-        res = self.search_batch(
-            np.asarray(query_vec, np.float32)[None, :], s, k=k, eps=eps)
-        return np.asarray(res.ids)[0], np.asarray(res.dists)[0]
+        with span("deg.search_from", device=True):
+            res = self.search_batch(
+                np.asarray(query_vec, np.float32)[None, :], s, k=k, eps=eps)
+            return np.asarray(res.ids)[0], np.asarray(res.dists)[0]
 
     def _search_from_batch(self, query_vecs: np.ndarray,
                            seed_ids: np.ndarray, k: int, eps: float

@@ -36,6 +36,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 INVALID = -1
 
 # full re-upload beats the gather+scatter once more than capacity / this
@@ -204,33 +206,34 @@ class GraphBuilder:
         :class:`DEGraph` is invalidated by this call whenever there were
         pending writes.  Dirty-row counts are bucketed to powers of two so
         repeated waves reuse a handful of compiled entries."""
-        if (self._dev_adj is None
-                or self._dev_adj.shape != self.adjacency.shape):
+        full = (self._dev_adj is None
+                or self._dev_adj.shape != self.adjacency.shape)
+        if full or self._dirty:
+            with span("deg.graph.sync", device=True):
+                self._sync(full)
+        return DEGraph(adjacency=self._dev_adj, weights=self._dev_w,
+                       n=jnp.asarray(self.n, dtype=jnp.int32))
+
+    def _sync(self, full: bool) -> None:
+        """Bring the device twin up to date: the whole buffers, or the
+        dirty rows scattered in."""
+        rows = None if full else np.fromiter(self._dirty, dtype=np.int32)
+        if full or rows.size * _FULL_SYNC_FRACTION >= self.capacity:
             self._drop_cache()         # stale twins must fail loudly
             self._dev_adj = jnp.asarray(self.adjacency)
             self._dev_w = jnp.asarray(self.weights)
-            self._dirty = set()
-            self._dev_sync_gen = self._gen
-        elif self._dirty:
-            rows = np.fromiter(self._dirty, dtype=np.int32)
-            if rows.size * _FULL_SYNC_FRACTION >= self.capacity:
-                self._drop_cache()
-                self._dev_adj = jnp.asarray(self.adjacency)
-                self._dev_w = jnp.asarray(self.weights)
-            else:
-                rows.sort()
-                width = pow2_bucket(rows.size)
-                # idempotent pad: repeat the last dirty row
-                rows = np.concatenate(
-                    [rows, np.full(width - rows.size, rows[-1], np.int32)])
-                self._dev_adj, self._dev_w = _scatter_rows(
-                    self._dev_adj, self._dev_w, jnp.asarray(rows),
-                    jnp.asarray(self.adjacency[rows]),
-                    jnp.asarray(self.weights[rows]))
-            self._dirty = set()
-            self._dev_sync_gen = self._gen
-        return DEGraph(adjacency=self._dev_adj, weights=self._dev_w,
-                       n=jnp.asarray(self.n, dtype=jnp.int32))
+        else:
+            rows.sort()
+            width = pow2_bucket(rows.size)
+            # idempotent pad: repeat the last dirty row
+            rows = np.concatenate(
+                [rows, np.full(width - rows.size, rows[-1], np.int32)])
+            self._dev_adj, self._dev_w = _scatter_rows(
+                self._dev_adj, self._dev_w, jnp.asarray(rows),
+                jnp.asarray(self.adjacency[rows]),
+                jnp.asarray(self.weights[rows]))
+        self._dirty = set()
+        self._dev_sync_gen = self._gen
 
     # -- mutation --------------------------------------------------------
     def _free_slot(self, v: int) -> int:

@@ -28,6 +28,8 @@ from typing import Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.trace import span
+
 from .build import DEGIndex, np_pair_dist
 from .graph import INVALID, pow2_bucket
 from .mrng import check_mrng, mrng_conform_mask
@@ -72,6 +74,8 @@ def optimize_edge(index: DEGIndex, v1: int, v2: int, *, i_opt: int = 5,
                   first_search: Optional[tuple] = None,
                   first_found: Optional[tuple] = None) -> bool:
     """Algorithm 4. Returns True iff the graph was improved (changes kept).
+    One ``deg.refine.edge`` span, under whichever root called it (Alg. 3
+    line 17 under ``deg.add``, Alg. 5 under ``deg.refine``).
 
     ``first_search`` optionally supplies a prefetched (ids, dists) result
     for the first step-(2) candidate search (the batched Alg. 5 path);
@@ -84,6 +88,14 @@ def optimize_edge(index: DEGIndex, v1: int, v2: int, *, i_opt: int = 5,
     against the live builder (and its gain recomputed) before being taken,
     falling back to the host scan when stale.
     """
+    with span("deg.refine.edge"):
+        return _optimize_edge(index, v1, v2, i_opt, k_opt, eps_opt,
+                              first_search, first_found)
+
+
+def _optimize_edge(index: DEGIndex, v1: int, v2: int, i_opt: int,
+                   k_opt: int, eps_opt: float, first_search: Optional[tuple],
+                   first_found: Optional[tuple]) -> bool:
     b = index.builder
     metric = index.params.metric
     vecs = index.vectors
@@ -234,9 +246,6 @@ def refine_sweep(index: DEGIndex, vertices: Sequence[int], *,
     matches the serial driver even on CPU and removes the per-edge
     host->device round-trip that dominates on accelerators.
     """
-    from repro.obs import clock
-    from .extend import mrng_conform_batch, propose_swaps
-
     b = index.builder
     if b is None or b.n <= b.degree + 1:
         return 0
@@ -244,77 +253,89 @@ def refine_sweep(index: DEGIndex, vertices: Sequence[int], *,
     improved = 0
     verts = [int(v) for v in vertices]
     for c0 in range(0, len(verts), chunk):
-        t_chunk = clock.now()
-        if c0:
-            # chunk boundary = invariant-clean point; same checkpoint
-            # cadence as _insert_wave (persist/snapshot.py).  Epoch
-            # republish ticks ride the same boundary so long sweeps
-            # surface improvements to live readers mid-run.
-            index._checkpoint_tick()
-            index._publish_tick()
-        verts_c = verts[c0:c0 + chunk]
-        # batched Alg. 2: conformity of every chunk edge in ONE device call,
-        # cached for the chunk instead of a host neighbor scan per vertex
-        g = b.device_graph()
-        conform = np.asarray(mrng_conform_batch(
-            g.adjacency, g.weights, index._dev_vectors,
-            jnp.asarray(np.asarray(verts_c, np.int32)),
-            metric=index.params.metric))
-        tasks = [(v1, v2) for i, v1 in enumerate(verts_c)
-                 for v2 in _edge_tasks(b, v1, conform=conform[i])]
-        if not tasks:
-            continue
-        # lane j: query = vectors[v2], seed = v1  (the (v3,v4)=(v1,v1) seeds
-        # of Alg. 4's first iteration)
-        q = index.vectors[np.asarray([v2 for _, v2 in tasks])]
-        seeds = np.asarray([[v1] for v1, _ in tasks], np.int32)
-        ids, dists = index._search_from_batch(q, seeds, k_opt, eps_opt)
-        # batched Alg. 4 step (2): every task's first swap decision in ONE
-        # device call against the pre-surgery chunk graph (lanes padded to
-        # a power of two so sweeps reuse a handful of jit entries)
-        T = len(tasks)
-        Tp = pow2_bucket(T, floor=4)
-        p_ids = np.full((Tp, ids.shape[1]), INVALID, np.int32)
-        p_ids[:T] = ids
-        p_d = np.full((Tp, ids.shape[1]), np.inf, np.float32)
-        p_d[:T] = dists
-        v1s = np.zeros((Tp,), np.int32)
-        v1s[:T] = [v1 for v1, _ in tasks]
-        v2s = np.zeros((Tp,), np.int32)
-        v2s[:T] = [v2 for _, v2 in tasks]
-        gains = np.zeros((Tp,), np.float32)
-        gains[:T] = [b.edge_weight(v1, v2) for v1, v2 in tasks]
-        prop = [np.asarray(x) for x in propose_swaps(
-            g.adjacency, g.weights, jnp.asarray(p_ids), jnp.asarray(p_d),
-            jnp.asarray(v1s), jnp.asarray(v2s), jnp.asarray(gains))]
-        clean = True     # no surgery since the chunk snapshot was taken
-        for t, ((v1, v2), lane_ids, lane_d) in enumerate(
-                zip(tasks, ids, dists)):
-            if not b.has_edge(v1, v2):     # removed by an earlier swap
-                continue
-            # a found=True proposal is re-validated live inside
-            # optimize_edge, so it stays usable on a mutated chunk; the
-            # found=False shortcut (skip the attempt entirely) is only
-            # sound while the chunk snapshot still matches the graph —
-            # a reverted attempt restores it exactly, a kept one doesn't.
-            p_found = bool(prop[4][t])
-            first_found = ((prop[0][t], prop[1][t], prop[2][t], p_found)
-                           if (p_found or clean) else None)
-            changed = optimize_edge(
-                index, v1, v2, i_opt=i_opt, k_opt=k_opt, eps_opt=eps_opt,
-                first_search=(lane_ids, lane_d), first_found=first_found)
-            improved += int(changed)
-            clean = clean and not changed
-        if metrics is not None:
-            # refine telemetry: per-chunk span + swap yield, so the
-            # continuous-refinement loop's cost/benefit shows up next to
-            # the serving metrics it shares a host with
-            metrics.histogram("refine_chunk_ms").observe(
-                (clock.now() - t_chunk) * 1e3)
-            metrics.counter("refine_edge_tasks_total").inc(len(tasks))
+        with span("deg.refine.chunk", metrics, metric="refine_chunk",
+                  chunk=c0 // chunk):
+            improved += _refine_chunk(index, verts[c0:c0 + chunk], c0 > 0,
+                                      i_opt, k_opt, eps_opt)
     if metrics is not None and verts:
         metrics.counter("refine_improved_edges_total").inc(improved)
         metrics.counter("refine_vertices_total").inc(len(verts))
     if verts:
         index._checkpoint_tick()
+    return improved
+
+
+def _refine_chunk(index: DEGIndex, verts_c: list, tick: bool, i_opt: int,
+                  k_opt: int, eps_opt: float) -> int:
+    """One chunk of :func:`refine_sweep`; returns its improved edges."""
+    from .extend import mrng_conform_batch, propose_swaps
+
+    b = index.builder
+    if tick:
+        # chunk boundary = invariant-clean point; same checkpoint cadence
+        # as _insert_wave (persist/snapshot.py).  Epoch republish ticks
+        # ride the same boundary so long sweeps surface improvements to
+        # live readers mid-run.
+        index._checkpoint_tick()
+        index._publish_tick()
+    # batched Alg. 2: conformity of every chunk edge in ONE device call,
+    # cached for the chunk instead of a host neighbor scan per vertex
+    g = b.device_graph()
+    with span("deg.refine.conform", device=True):
+        conform = np.asarray(mrng_conform_batch(
+            g.adjacency, g.weights, index._dev_vectors,
+            jnp.asarray(np.asarray(verts_c, np.int32)),
+            metric=index.params.metric))
+    tasks = [(v1, v2) for i, v1 in enumerate(verts_c)
+             for v2 in _edge_tasks(b, v1, conform=conform[i])]
+    if not tasks:
+        return 0
+    # lane j: query = vectors[v2], seed = v1  (the (v3,v4)=(v1,v1) seeds
+    # of Alg. 4's first iteration)
+    q = index.vectors[np.asarray([v2 for _, v2 in tasks])]
+    seeds = np.asarray([[v1] for v1, _ in tasks], np.int32)
+    with span("deg.refine.search_batch", device=True):
+        ids, dists = index._search_from_batch(q, seeds, k_opt, eps_opt)
+    # batched Alg. 4 step (2): every task's first swap decision in ONE
+    # device call against the pre-surgery chunk graph (lanes padded to a
+    # power of two so sweeps reuse a handful of jit entries)
+    T = len(tasks)
+    Tp = pow2_bucket(T, floor=4)
+    p_ids = np.full((Tp, ids.shape[1]), INVALID, np.int32)
+    p_ids[:T] = ids
+    p_d = np.full((Tp, ids.shape[1]), np.inf, np.float32)
+    p_d[:T] = dists
+    v1s = np.zeros((Tp,), np.int32)
+    v1s[:T] = [v1 for v1, _ in tasks]
+    v2s = np.zeros((Tp,), np.int32)
+    v2s[:T] = [v2 for _, v2 in tasks]
+    gains = np.zeros((Tp,), np.float32)
+    gains[:T] = [b.edge_weight(v1, v2) for v1, v2 in tasks]
+    with span("deg.refine.propose", device=True):
+        prop = [np.asarray(x) for x in propose_swaps(
+            g.adjacency, g.weights, jnp.asarray(p_ids), jnp.asarray(p_d),
+            jnp.asarray(v1s), jnp.asarray(v2s), jnp.asarray(gains))]
+    improved = 0
+    clean = True     # no surgery since the chunk snapshot was taken
+    for t, ((v1, v2), lane_ids, lane_d) in enumerate(zip(tasks, ids, dists)):
+        if not b.has_edge(v1, v2):     # removed by an earlier swap
+            continue
+        # a found=True proposal is re-validated live inside optimize_edge,
+        # so it stays usable on a mutated chunk; the found=False shortcut
+        # (skip the attempt entirely) is only sound while the chunk
+        # snapshot still matches the graph — a reverted attempt restores
+        # it exactly, a kept one doesn't.
+        p_found = bool(prop[4][t])
+        first_found = ((prop[0][t], prop[1][t], prop[2][t], p_found)
+                       if (p_found or clean) else None)
+        changed = optimize_edge(
+            index, v1, v2, i_opt=i_opt, k_opt=k_opt, eps_opt=eps_opt,
+            first_search=(lane_ids, lane_d), first_found=first_found)
+        improved += int(changed)
+        clean = clean and not changed
+    if index.metrics is not None:
+        # swap yield per chunk, next to the refine_chunk_ms span, so the
+        # continuous-refinement loop's cost/benefit shows up beside the
+        # serving metrics it shares a host with
+        index.metrics.counter("refine_edge_tasks_total").inc(len(tasks))
     return improved

@@ -55,6 +55,9 @@ class SearchResult:
     # evals — which the query log records per query (obs/querylog.py).
     # One cheap reduction over state already on device: free telemetry.
     visited_frac: Optional[Array] = None
+    # () int32 — the beam loop's trip count (BeamState.trips): each trip
+    # runs all B lanes, finished and padded ones included
+    trips: Optional[Array] = None
 
 
 def exact_rerank(exact_vectors: Array, queries: Array, cand_ids: Array,
@@ -188,7 +191,8 @@ def range_search(
         visited_frac = jnp.mean((state.visited != INVALID)
                                 .astype(jnp.float32), axis=1)
     return SearchResult(ids=out_ids, dists=out_d, hops=state.hops,
-                        evals=evals, visited_frac=visited_frac)
+                        evals=evals, visited_frac=visited_frac,
+                        trips=state.trips)
 
 
 def medoid_seed(vectors: Array, n: int) -> int:

@@ -64,7 +64,7 @@ import numpy as np
 from repro.obs import clock
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.querylog import LATENCY_METRIC, QueryLogWriter, make_record
-from repro.obs.trace import Sampler
+from repro.obs.trace import Sampler, span
 from repro.resilience import faults as _faults
 from repro.resilience.degrade import (DegradePolicy, LadderController,
                                       LadderRung, build_ladder)
@@ -187,6 +187,10 @@ class AsyncQueryEngine:
         self._m_partials = self.metrics.counter(
             "serving_deadline_partials_total")
         self._m_hops = self.metrics.counter("serving_hops_total")
+        # beam-loop trips x bucket width: the lane-trips a flush ran,
+        # finished and padded lanes included (hops / lane_trips is the
+        # share of them that expanded a vertex, at expand_width 1)
+        self._m_lane_trips = self.metrics.counter("serving_lane_trips_total")
         self._m_evals = self.metrics.counter("serving_evals_total")
         self._m_queue_depth = self.metrics.gauge("serving_queue_depth")
         self._m_latency = self.metrics.histogram(LATENCY_METRIC)
@@ -544,12 +548,18 @@ class AsyncQueryEngine:
     def _dispatch(self, reqs: list[Request]) -> None:
         """Stage one bucketed flush and enqueue it (asynchronously — jax
         returns before the device finishes) for the extract thread."""
+        B = len(reqs)
+        bucket = next(b for b in self.buckets if b >= B)
+        with span("deg.serve.dispatch", flush=self.stats.flushes,
+                  bucket=bucket, lanes=B):
+            self._dispatch_flush(reqs, B, bucket)
+
+    def _dispatch_flush(self, reqs: list[Request], B: int,
+                        bucket: int) -> None:
         # _staging lets the crash handler fail a batch that was popped
         # from the queue but never made it into the in-flight pipeline
         self._staging = reqs
-        _faults.fire("scheduler.dispatch", batch=len(reqs))
-        B = len(reqs)
-        bucket = next(b for b in self.buckets if b >= B)
+        _faults.fire("scheduler.dispatch", batch=B)
         # degradation ladder: backlog left *after* popping this batch is
         # the pressure signal; the whole flush dispatches at one rung
         level = 0
@@ -638,60 +648,72 @@ class AsyncQueryEngine:
             self._extracting = item
             _faults.fire("extract.loop")
             reqs, res, expired, bucket, t0, view = item
-            B = len(reqs)
-            ids = np.asarray(res.ids)      # device->host: blocks until the
-            dists = np.asarray(res.dists)  # async dispatch finished
+            flush = reqs[0].result.flush_index
+            with span("deg.serve.readback", device=True, flush=flush):
+                ids = np.asarray(res.ids)      # device->host: blocks until
+                dists = np.asarray(res.dists)  # the async dispatch finished
             t_dev = clock.now()
-            dt = t_dev - t0
-            self.stats.ema_flush_s = dt if not self.stats.ema_flush_s \
-                else 0.8 * self.stats.ema_flush_s + 0.2 * dt
-            self._m_flush_lat[bucket].observe(dt * 1e3)
-            # traversal counters ride the same result the flush computed
-            # anyway — surfacing them costs two tiny transfers, zero
-            # extra device work
-            hops = np.asarray(res.hops)
-            evals = np.asarray(res.evals)
-            self._m_hops.inc(int(hops[:B].sum()))
-            self._m_evals.inc(int(evals[:B].sum()))
-            vfrac = None if res.visited_frac is None \
-                else np.asarray(res.visited_frac)
-            # every device read of this flush is on host: drop the epoch
-            # reference (clearing the slot keeps a crash-drain from
-            # double-releasing this item)
-            item[5] = None
-            self.index.release_view(view)
-            log = self._query_log
-            any_sampled = log is not None and any(
-                r.result.sampled for r in reqs)
-            for i, r in enumerate(reqs):
-                if expired[i]:
-                    self.stats.partials += 1
-                    self._m_partials.inc()
-                r.result.device_done_at = t_dev
-                r.result._complete(ids[i].copy(), dists[i].copy(),
-                                   partial=expired[i])
-                # observe AFTER _complete so the histogram sees the same
-                # completed_at the future exposes (log replay matches)
-                self._m_latency.observe(
-                    (r.result.completed_at - r.result.submitted_at) * 1e3)
-                if any_sampled and r.result.sampled:
-                    log.write(make_record(
-                        qid=r.seq, query=r.query, k=self.cfg.k,
-                        ids=ids[i], dists=dists[i],
-                        hops=int(hops[i]), evals=int(evals[i]),
-                        seed_vertex=r.seed_vertex,
-                        exclude_n=len(r.exclude),
-                        visited_frac=None if vfrac is None
-                        else float(vfrac[i]),
-                        budget_exhausted=bool(
-                            expired[i] and self.partial_hops is not None
-                            and hops[i] >= self.partial_hops),
-                        partial=expired[i],
-                        flush_index=r.result.flush_index, bucket=bucket,
-                        latency_ms=(r.result.completed_at
-                                    - r.result.submitted_at) * 1e3,
-                        result=r.result,
-                        t_mono=r.result.submitted_at))
+            with span("deg.serve.complete", flush=flush):
+                self._complete_flush(item, ids, dists, t_dev)
             self._extracting = None
             self._slots.release()     # free the dispatch slot last, so a
             # newly formed batch sees this flush's arrivals in the queue
+
+    def _complete_flush(self, item: list, ids: np.ndarray, dists: np.ndarray,
+                        t_dev: float) -> None:
+        """Counters, futures and query log of one flush whose answers are
+        on host; releases its epoch view."""
+        reqs, res, expired, bucket, t0, view = item
+        B = len(reqs)
+        dt = t_dev - t0
+        self.stats.ema_flush_s = dt if not self.stats.ema_flush_s \
+            else 0.8 * self.stats.ema_flush_s + 0.2 * dt
+        self._m_flush_lat[bucket].observe(dt * 1e3)
+        # traversal counters ride the same result the flush computed
+        # anyway — surfacing them costs three tiny transfers, zero
+        # extra device work
+        hops = np.asarray(res.hops)
+        evals = np.asarray(res.evals)
+        self._m_hops.inc(int(hops[:B].sum()))
+        self._m_evals.inc(int(evals[:B].sum()))
+        if res.trips is not None:
+            self._m_lane_trips.inc(int(res.trips) * bucket)
+        vfrac = None if res.visited_frac is None \
+            else np.asarray(res.visited_frac)
+        # every device read of this flush is on host: drop the epoch
+        # reference (clearing the slot keeps a crash-drain from
+        # double-releasing this item)
+        item[5] = None
+        self.index.release_view(view)
+        log = self._query_log
+        any_sampled = log is not None and any(
+            r.result.sampled for r in reqs)
+        for i, r in enumerate(reqs):
+            if expired[i]:
+                self.stats.partials += 1
+                self._m_partials.inc()
+            r.result.device_done_at = t_dev
+            r.result._complete(ids[i].copy(), dists[i].copy(),
+                               partial=expired[i])
+            # observe AFTER _complete so the histogram sees the same
+            # completed_at the future exposes (log replay matches)
+            self._m_latency.observe(
+                (r.result.completed_at - r.result.submitted_at) * 1e3)
+            if any_sampled and r.result.sampled:
+                log.write(make_record(
+                    qid=r.seq, query=r.query, k=self.cfg.k,
+                    ids=ids[i], dists=dists[i],
+                    hops=int(hops[i]), evals=int(evals[i]),
+                    seed_vertex=r.seed_vertex,
+                    exclude_n=len(r.exclude),
+                    visited_frac=None if vfrac is None
+                    else float(vfrac[i]),
+                    budget_exhausted=bool(
+                        expired[i] and self.partial_hops is not None
+                        and hops[i] >= self.partial_hops),
+                    partial=expired[i],
+                    flush_index=r.result.flush_index, bucket=bucket,
+                    latency_ms=(r.result.completed_at
+                                - r.result.submitted_at) * 1e3,
+                    result=r.result,
+                    t_mono=r.result.submitted_at))
