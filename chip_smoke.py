@@ -4,7 +4,8 @@
 Run from the root of a checkout, in one process that owns the chip:
 
     python chip_smoke.py              # one chip: build, serve, explore,
-                                      # mutate live, fused hop
+                                      # mutate live, fused hop,
+                                      # the table's layout
     python chip_smoke.py --chips 4    # four chips: the sharded index only
 
 The configuration is the paper's ``audio`` deployment (Table 2/3: 53,387
@@ -182,8 +183,9 @@ def engine(idx, **kw):
 
 # -- one chip --------------------------------------------------------------
 def run_one_chip(n: int, seed: int, assert_kernel=None) -> None:
+    from repro.analysis.hlo import result_copies
     from repro.core.build import build_deg
-    from repro.core.search import range_search
+    from repro.core.search import range_search, table_format
     from repro.core.visited import default_size
 
     base, extra, queries = dataset(n, seed)
@@ -314,6 +316,25 @@ def run_one_chip(n: int, seed: int, assert_kernel=None) -> None:
     check(abs(rf - rt) <= 0.01,
           f"fused recall {rf:.4f} vs jnp twin {rt:.4f} differ > 0.01")
 
+    # -- the table's layout: the beam reads it in place or staged on chip --
+    p = idx.params
+    table = idx._dev_vectors
+    text = range_search.lower(
+        idx.frozen(), table, extra[:1], np.array([[0, 1]], np.int32),
+        k=p.k_opt, eps=p.eps_opt).compile().as_text()
+    copies = result_copies(text, "f32", table.shape)
+    layout = table.format.layout
+    staged = table_format(table.sharding, table.shape).layout is None
+    say("table_layout", shape="x".join(map(str, table.shape)),
+        major_to_minor=",".join(map(str, layout.major_to_minor)),
+        tiling=str(layout.tiling).replace(" ", ""), staged_on_chip=staged,
+        table_sized_copies=len(copies))
+    if staged:
+        check(copies and all("S(1)" in t for _, t in copies),
+              f"a table that fits on chip is not staged there: {copies}")
+    else:
+        check(not copies, f"the single-lane search copies the whole "
+                          f"table: {copies}")
 
 def _assert_tpu_kernel(program_text: str) -> None:
     check("tpu_custom_call" in program_text,

@@ -146,6 +146,17 @@ def parse_module(text: str) -> dict[str, Computation]:
     return comps
 
 
+def result_copies(text: str, dtype: str, dims: tuple
+                  ) -> list[tuple[str, str]]:
+    """(name, result type) of each ``copy`` instruction of a compiled module
+    whose result is ``dtype[dims]``, in any layout or memory space (a
+    relayout, or a staging copy on chip: ``S(1)`` in the type)."""
+    return sorted({(i.name, i.type_str) for c in parse_module(text).values()
+                   for i in c.instrs
+                   if i.opcode == "copy" and i.type_str.startswith(dtype + "[")
+                   and shape_dims(i.type_str) == tuple(dims)})
+
+
 def _parse_operand_names(opsec: str) -> list:
     """Operand names from an operand list section.
 

@@ -18,14 +18,18 @@ Two build modes:
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import math
 import threading
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+# a thread-local setting that jax exposes nowhere else; see _fresh_compiles
+from jax._src import config as _jax_config
 
 from repro.obs.trace import span
 
@@ -33,7 +37,7 @@ from .distances import get_metric
 from .graph import (DEGraph, GraphBuilder, INVALID, complete_graph,
                     pow2_bucket)
 from .mrng import check_mrng_candidate
-from .search import SearchResult, medoid_seed, range_search
+from .search import SearchResult, medoid_seed, range_search, table_format
 
 
 # ---------------------------------------------------------------------------
@@ -111,9 +115,66 @@ def _locked(fn):
     return wrapper
 
 
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _write_rows(buf: jax.Array, rows: jax.Array, start: jax.Array) -> jax.Array:
+@contextlib.contextmanager
+def _fresh_compiles(fmt):
+    """Keep what compiles inside out of the persistent compilation cache
+    where it returns a table in a layout of its own (``fmt.layout`` set).
+
+    An executable read back from that cache marks its outputs with the
+    backend's default layout, whatever layout it writes them in (jax 0.9,
+    on the CPU and on a v5e alike): a table it returns in ``TABLE_LAYOUT``
+    would be read as column-major by every later program.  So each process
+    compiles the programs that return such a table itself.  Programs that
+    only take the table, as ``range_search`` does, read back correctly."""
+    if fmt.layout is None:
+        yield
+        return
+    with _jax_config.persistent_cache_min_compile_time_secs(math.inf):
+        yield
+
+
+def _zeros_table(shape: tuple) -> jax.Array:
+    return jnp.zeros(shape, jnp.float32)
+
+
+def _copy_table(table: jax.Array) -> jax.Array:
+    return table
+
+
+def _write_table_rows(buf: jax.Array, rows: jax.Array, start: jax.Array
+                      ) -> jax.Array:
     return jax.lax.dynamic_update_slice(buf, rows, (start, jnp.int32(0)))
+
+
+class _TablePrograms:
+    """The programs that hand back a vector table in one format: zeros, a
+    copy (of host rows, or of a device table in any layout), and the
+    donated row update in place.  Each pins its output to the format
+    through ``out_shardings``; see :func:`_fresh_compiles`."""
+
+    def __init__(self, fmt):
+        self.format = fmt
+        self._zeros = jax.jit(_zeros_table, static_argnums=(0,),
+                              out_shardings=fmt)
+        self._copy = jax.jit(_copy_table, out_shardings=fmt)
+        self._write = jax.jit(_write_table_rows, donate_argnums=(0,),
+                              out_shardings=fmt)
+
+    def zeros(self, shape: tuple) -> jax.Array:
+        with _fresh_compiles(self.format):
+            return self._zeros(shape)
+
+    def copy(self, table) -> jax.Array:
+        with _fresh_compiles(self.format):
+            return self._copy(table)
+
+    def write_rows(self, buf: jax.Array, rows: jax.Array,
+                   start: jax.Array) -> jax.Array:
+        with _fresh_compiles(self.format):
+            return self._write(buf, rows, start)
+
+
+_table_programs = functools.cache(_TablePrograms)
 
 
 class DEGIndex:
@@ -125,7 +186,10 @@ class DEGIndex:
         self.dim = dim
         capacity = max(capacity, self.params.degree + 1)
         self.vectors = np.zeros((capacity, dim), dtype=np.float32)
-        self._dev_vectors = jnp.zeros((capacity, dim), dtype=jnp.float32)
+        # the device table, in the format every search reads it in
+        # (core/search.py::table_format), made on the device
+        self._table = self._programs_for(capacity)
+        self._dev_vectors = self._table.zeros((capacity, dim))
         self.builder: Optional[GraphBuilder] = None
         self._pending: list[np.ndarray] = []   # points before K_{d+1} exists
         self._rng = np.random.default_rng(0)
@@ -187,17 +251,40 @@ class DEGIndex:
         vecs = np.zeros((new_capacity, self.dim), dtype=np.float32)
         vecs[: self.capacity] = self.vectors
         self.vectors = vecs
-        self._dev_vectors = jnp.asarray(vecs)
+        self._table = self._programs_for(new_capacity)
+        self._dev_vectors = self._table.copy(vecs)
+        self._placed_table()
         self._stores = {}
         if self.builder is not None:
             self.builder.grow(new_capacity)
 
     # -- device sync ---------------------------------------------------------
+    def _programs_for(self, capacity: int) -> _TablePrograms:
+        """The table programs for a table of ``capacity`` rows, in the
+        format core/search.py::table_format gives that size."""
+        sharding = jax.sharding.SingleDeviceSharding(jax.devices()[0])
+        return _table_programs(table_format(sharding, (capacity, self.dim)))
+
+    def _placed_table(self) -> jax.Array:
+        """The device table, re-placed in the search layout if it is kept
+        in that layout and some path dropped it (counted as
+        ``vector_table_relayouts_total``: each later search would otherwise
+        relayout the whole table first)."""
+        want = self._table.format.layout
+        if (want is not None and self._dev_vectors.format.layout
+                .major_to_minor != want.major_to_minor):
+            from repro.obs.metrics import VECTOR_TABLE_RELAYOUTS_TOTAL
+
+            self._dev_vectors = self._table.copy(self._dev_vectors)
+            if self.metrics is not None:
+                self.metrics.counter(VECTOR_TABLE_RELAYOUTS_TOTAL).inc()
+        return self._dev_vectors
+
     def _put_rows(self, rows: np.ndarray, start: int) -> None:
         self._medoid = None                    # vector set changed
         self._stores = {}
-        self._dev_vectors = _write_rows(
-            self._dev_vectors, jnp.asarray(rows, dtype=jnp.float32),
+        self._dev_vectors = self._table.write_rows(
+            self._placed_table(), jnp.asarray(rows, dtype=jnp.float32),
             jnp.asarray(start, dtype=jnp.int32))
 
     def medoid(self) -> int:
@@ -279,7 +366,7 @@ class DEGIndex:
                                   "quarantine": [int(q) for q in quar]}, {})
             ep = PublishedEpoch(
                 epoch=e, graph=self.builder.freeze(),
-                vectors=jnp.array(self._dev_vectors), n=self.n,
+                vectors=self._table.copy(self._placed_table()), n=self.n,
                 medoid_id=self._publish_medoid(quar),
                 metric=self.params.metric, params=self.params,
                 quarantine=quar, builder_gen=gen)

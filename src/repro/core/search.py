@@ -33,6 +33,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Format, Layout
 
 from . import beam
 from .beam import neighbor_distances_jnp as _neighbor_distances_jnp  # noqa: F401  (re-export)
@@ -40,6 +41,46 @@ from .distances import get_metric
 from .graph import DEGraph, INVALID
 
 Array = jax.Array
+
+#: The search layout of a vector table: row-major, the layout the beam's
+#: row gathers read.  A TPU's default layout for a table whose rows are not
+#: a whole number of 128-lane tiles can be column-major (it pads less), and
+#: a ``range_search`` handed such a table relayouts all of it before its
+#: first hop, on every call.
+TABLE_LAYOUT = Layout(major_to_minor=(0, 1))
+
+
+def _row_major_bytes(shape: tuple) -> int:
+    """Bytes of a float32 (rows, dim) table row-major in (8, 128) tiles."""
+    rows, dim = shape
+    return -(-rows // 8) * 8 * (-(-dim // 128) * 128) * 4
+
+
+def _on_chip_bytes(device) -> int:
+    """The on-chip memory (VMEM) of a TPU device's core, that its compiler
+    stages operands into; 0 for any other device."""
+    if device.platform != "tpu":
+        return 0
+    from jax.experimental.pallas import tpu as pltpu
+
+    with jax.default_device(device):
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def table_format(sharding: jax.sharding.Sharding, shape: tuple) -> Format:
+    """The format a float32 (rows, dim) vector table is kept in on
+    ``sharding``'s device.
+
+    :data:`TABLE_LAYOUT` once the table's row-major bytes exceed the
+    device's on-chip memory.  A table that fits there keeps the backend's
+    default layout (layout ``None``): the compiler then stages a row-major
+    copy of it on chip at each call, and the beam's gathers read that copy
+    faster than a row-major table in HBM.  A committed argument is
+    compiled in its own layout, so every jitted program that takes the
+    table reads it in this format."""
+    (device,) = sharding.device_set
+    staged = _row_major_bytes(shape) <= _on_chip_bytes(device)
+    return Format(None if staged else TABLE_LAYOUT, sharding)
 
 
 @jax.tree_util.register_dataclass

@@ -61,6 +61,10 @@ EPOCH_RETIRED_LAG_MS = "epoch_retired_lag_ms"
 SCRUB_AUDITED_TOTAL = "scrub_vertices_audited_total"
 SCRUB_QUARANTINED_TOTAL = "scrub_quarantined_total"
 SCRUB_REPAIRED_TOTAL = "scrub_repaired_total"
+# Times DEGIndex found its device vector table out of the search layout
+# (core/search.py::TABLE_LAYOUT) and re-placed it; 0 unless some path drops
+# the layout, after which every search would relayout the table again.
+VECTOR_TABLE_RELAYOUTS_TOTAL = "vector_table_relayouts_total"
 
 
 def _label_key(labels: dict) -> tuple:

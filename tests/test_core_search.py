@@ -262,3 +262,147 @@ def test_medoid_seed_cached_and_invalidated(small_index):
     assert idx._medoid is None
     m1 = idx.medoid()
     assert 0 <= m1 < idx.n
+
+
+def _table_case(path, tmp_path):
+    """An index after one of the paths that make or replace its device
+    table, with a metrics registry attached before that path ran; returns
+    (index, the searchable view, its table)."""
+    from repro.core import DEGIndex
+    from repro.obs.metrics import MetricsRegistry
+
+    base, _ = make_dataset("gaussian", 300, 1, 16, seed=11)
+    p = DEGParams(degree=8, k_ext=16, eps_ext=0.3, k_opt=8)
+    idx = DEGIndex(16, p, capacity=512)
+    idx.metrics = MetricsRegistry()
+    idx.add(base[:240], wave_size=16)
+    if path == "grow":
+        idx.grow(1024)
+    elif path == "publish":
+        idx.enable_publishing()
+        idx.add(base[240:], wave_size=16)
+        idx.publish()
+        view = idx.acquire_view()
+        idx.release_view(view)
+        return idx, view, view.vectors
+    elif path == "load":
+        idx.save(tmp_path / "idx.npz")
+        idx = DEGIndex.load(tmp_path / "idx.npz")
+        idx.metrics = MetricsRegistry()
+        idx.add(base[240:], wave_size=16)
+    return idx, idx, idx._dev_vectors
+
+
+@pytest.mark.parametrize("path", ["add", "grow", "publish", "load"])
+def test_vector_table_keeps_search_layout(path, tmp_path):
+    """Every path that makes or replaces the device table leaves it in
+    the row-major search format, without a relayout, and the searches
+    over it answer as over a plain device copy of the same rows."""
+    from repro.core.search import TABLE_LAYOUT
+    from repro.obs.metrics import VECTOR_TABLE_RELAYOUTS_TOTAL
+
+    idx, view, table = _table_case(path, tmp_path)
+    assert table.format.layout.major_to_minor == TABLE_LAYOUT.major_to_minor
+    assert idx.metrics.counter(VECTOR_TABLE_RELAYOUTS_TOTAL).value == 0
+    rng = np.random.default_rng(5)
+    q = rng.normal(size=(6, 16)).astype(np.float32)
+    seeds = rng.integers(0, view.n, size=(6, 2)).astype(np.int32)
+    got = view.search_batch(q, seeds, k=10, eps=0.2)
+    graph = view.graph if path == "publish" else idx.frozen()
+    want = range_search(graph, jnp.asarray(np.asarray(table)),
+                        jnp.asarray(q), jnp.asarray(seeds), k=10, eps=0.2)
+    for a, b in ((got.ids, want.ids), (got.dists, want.dists),
+                 (got.hops, want.hops), (got.evals, want.evals)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_dropped_table_layout_is_replaced_and_counted():
+    """A table that lost the search layout is re-placed at the next row
+    write, keeps its rows, and is counted once."""
+    import jax
+    from jax.experimental.layout import Format, Layout
+
+    from repro.core import DEGIndex
+    from repro.core.search import TABLE_LAYOUT
+    from repro.obs.metrics import (MetricsRegistry,
+                                   VECTOR_TABLE_RELAYOUTS_TOTAL)
+
+    base, _ = make_dataset("gaussian", 120, 1, 16, seed=12)
+    idx = DEGIndex(16, DEGParams(degree=8, k_ext=16), capacity=256)
+    idx.metrics = MetricsRegistry()
+    idx.add(base[:100], wave_size=16)
+    t = idx._dev_vectors
+    idx._dev_vectors = jax.device_put(
+        t, Format(Layout(major_to_minor=(1, 0)), t.sharding))
+    idx.add(base[100:], wave_size=16)
+    assert (idx._dev_vectors.format.layout.major_to_minor
+            == TABLE_LAYOUT.major_to_minor)
+    assert idx.metrics.counter(VECTOR_TABLE_RELAYOUTS_TOTAL).value == 1
+    np.testing.assert_array_equal(np.asarray(idx._dev_vectors), idx.vectors)
+
+
+_CACHED_TABLE_CHILD = """
+import json, sys
+import jax, jax.numpy as jnp, numpy as np
+jax.config.update("jax_compilation_cache_dir", sys.argv[1])
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+from jax.experimental.layout import Layout
+import repro.core.search as search
+search.TABLE_LAYOUT = Layout(major_to_minor=(1, 0))
+from repro.core import DEGIndex, DEGParams, range_search
+from repro.obs.metrics import MetricsRegistry, VECTOR_TABLE_RELAYOUTS_TOTAL
+rng = np.random.default_rng(0)
+x = rng.normal(size=(120, 16)).astype(np.float32)
+idx = DEGIndex(16, DEGParams(degree=8, k_ext=16), capacity=64)
+idx.metrics = MetricsRegistry()
+idx.add(x[:90], wave_size=16)
+idx.enable_publishing()
+idx.add(x[90:], wave_size=16)
+idx.publish()
+view = idx.acquire_view()
+q = rng.normal(size=(4, 16)).astype(np.float32)
+s = np.zeros((4, 1), np.int32)
+got = idx.search_batch(q, s, k=5, eps=0.2)
+want = range_search(idx.frozen(), jnp.asarray(x), jnp.asarray(q),
+                    jnp.asarray(s), k=5, eps=0.2)
+print(json.dumps({
+    "layout": list(idx._dev_vectors.format.layout.major_to_minor),
+    "relayouts": idx.metrics.counter(VECTOR_TABLE_RELAYOUTS_TOTAL).value,
+    "rows": bool(np.array_equal(np.asarray(idx._dev_vectors)[:120], x)),
+    "epoch_rows": bool(np.array_equal(np.asarray(view.vectors)[:120], x)),
+    "answers": bool(np.array_equal(np.asarray(got.ids), np.asarray(want.ids))
+                    and np.array_equal(np.asarray(got.dists),
+                                       np.asarray(want.dists)))}))
+"""
+
+
+def test_vector_table_survives_persistent_compile_cache(tmp_path):
+    """An executable read back from jax's persistent compilation cache
+    marks its outputs with the default layout, whatever layout it writes
+    them in (jax 0.9).  Two processes share one cache, with the table
+    kept column-major (not the CPU's default) so that the defect would
+    show: the second reads the search programs back, and must still hold
+    the rows and answer as over a plain table."""
+    import json
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+
+    src = str(Path(__file__).resolve().parents[1] / "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=os.pathsep.join(
+                   [src] + [p for p in [os.environ.get("PYTHONPATH")] if p]))
+    cache = tmp_path / "jax_cache"
+    cache.mkdir()
+    runs = []
+    for _ in range(2):
+        out = subprocess.run(
+            [sys.executable, "-c", _CACHED_TABLE_CHILD, str(cache)],
+            env=env, capture_output=True, text=True, timeout=300)
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    assert any(cache.iterdir())
+    for run in runs:
+        assert run == {"layout": [1, 0], "relayouts": 0.0, "rows": True,
+                       "epoch_rows": True, "answers": True}

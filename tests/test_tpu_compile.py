@@ -7,7 +7,8 @@ kernel with ``interpret=False`` through its ``ops`` wrapper at the paper's
 audio widths (dim 192, degree 20) and at dim 128 / degree 30, and check
 that the compiled program holds the kernel.  They also compile the default
 jnp ``range_search`` program at an audio-size corpus and check that it
-fits one chip's memory.
+fits one chip's memory, and Alg. 4's single-lane search at the Enron table
+in the index's table format and in the compiler's default one.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU compiler's library, and only the worker that
@@ -29,6 +30,8 @@ V5E_HBM_BYTES = 16 * 2**30
 #: dim-128 / degree-30 deployment (sift1m-like widths)
 SHAPES = [(53387, 192, 20), (65536, 128, 30)]
 BATCH = 64
+#: the paper's Enron deployment: 1369-wide rows, 11 lane tiles a row
+ENRON = (94987, 1369, 30)
 
 
 @pytest.fixture(scope="module")
@@ -124,3 +127,42 @@ def test_default_range_search_fits_v5e(one_chip):
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
     assert "tpu_custom_call" not in compiled.as_text()
+
+
+@pytest.mark.parametrize("table", ["search_format", "default_format"])
+def test_refine_search_reads_table_in_place(one_chip, table):
+    """Alg. 4's single-lane search (1 lane, 2 seeds, k 30, eps 0.001) over
+    the Enron table.  In the index's table format the program copies no
+    table-sized array.  In the compiler's default format, column-major for
+    these rows (it pads less), it relayouts the whole table before the
+    first hop: the reason the format exists."""
+    from repro.analysis.hlo import result_copies
+    from repro.core.graph import DEGraph
+    from repro.core.search import range_search, table_format
+
+    N, m, d = ENRON
+    S = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
+    fmt = (table_format(one_chip, (N, m)) if table == "search_format"
+           else one_chip)
+    graph = DEGraph(adjacency=S((N, d), jnp.int32), weights=S((N, d)),
+                    n=S((), jnp.int32))
+    text = range_search.lower(
+        graph, _spec(fmt, (N, m), jnp.float32), S((1, m)),
+        S((1, 2), jnp.int32), k=30, eps=0.001).compile().as_text()
+    copies = result_copies(text, "f32", (N, m))
+    if table == "search_format":
+        assert copies == []
+    else:
+        assert copies
+
+
+@pytest.mark.parametrize("N,m,staged", [(53387, 192, True),
+                                        (94987, 1369, False)])
+def test_table_format_follows_on_chip_memory(one_chip, N, m, staged):
+    """A table whose row-major bytes fit a v5e core's on-chip memory keeps
+    the default layout, which the compiler stages on chip row-major (audio:
+    55 MB of 128 MiB); a larger one is kept row-major (Enron: 535 MB)."""
+    from repro.core.search import TABLE_LAYOUT, table_format
+
+    layout = table_format(one_chip, (N, m)).layout
+    assert layout == (None if staged else TABLE_LAYOUT)
