@@ -15,8 +15,11 @@ The requests are those a run of the cell judges, made from the seed by the
 kind's ``control_requests`` (``bench/kinds/<kind>.py``): the open-loop
 schedule of ``run_seconds`` (BENCHMARK.json), or ``--requests``
 exploration queries drawn as the closed loop draws them, or the build
-cell's probe queries over ``--rows`` inserted rows.  The benchmark's own runs do not run this;
-``bench/tests/test_bench_control.py`` runs it at a small size.
+cell's probe queries over ``--rows`` inserted rows.  Where the kind
+restricts its requests to some rows (an exploration query's own vertex
+left out, a filter), the control searches those rows only.  The
+benchmark's own runs do not run this; ``bench/tests/test_bench_control.py``
+runs it at the sizes each configuration gives under ``control``.
 """
 from __future__ import annotations
 
@@ -37,18 +40,14 @@ def control_numbers(cfg: dict, mix: dict, seed: int, seconds: float,
 
     from bench import drivers, judge, reference
 
-    kind = drivers.load_kind(mix["kind"], bench_dir or drivers.BENCH_DIR)
-    base, q, truth, self_ids = kind.control_requests(
-        cfg, mix, seed, seconds, requests, rows)
-    k = cfg["search"]["k"]
-    kk = k + 1 if self_ids is not None else k
-    d, ids = reference.brute_force_control(q, base, kk)
-    if self_ids is not None:
-        keep = ids != self_ids[:, None]
-        ids = np.stack([r[m][:k] for r, m in zip(ids, keep)])
-        d = np.stack([r[m][:k] for r, m in zip(d, keep)])
+    bench_dir = bench_dir or drivers.BENCH_DIR
+    kind = drivers.load_kind(mix["kind"], bench_dir)
+    base, q, truth, admissible = kind.control_requests(
+        cfg, mix, seed, seconds, requests, rows, bench_dir)
+    k, metric = cfg["search"]["k"], cfg["metric"]
+    d, ids = reference.brute_force_control(q, base, k, admissible, metric)
     return judge.answer_numbers(base, q, ids, d, np.ones(len(q), bool),
-                                truth, k, self_ids=self_ids)
+                                truth, k, admissible, metric)
 
 
 def main(argv=None) -> int:

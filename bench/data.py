@@ -1,23 +1,29 @@
-"""Seeded corpora for the benchmark, made on the device in one jitted call.
+"""Seeded corpora for the benchmark, made by the generator a configuration
+names.
 
-``planted_manifold`` follows the form of the program's own generator
-(``data/synthetic.py``): points on a random smooth ``intrinsic_dim``-manifold
-(a degree-2 feature lift of Gaussian latents) projected into ``dim``
-dimensions, plus isotropic noise.  Real audio and text embeddings have a low
-intrinsic dimension; a corpus of that kind is what makes the paper's recall
-reachable without heavy refinement.  The benchmark keeps its own copy so that
-no later change to the program can change the data it is judged on.
-
-Base rows and query rows come from the same manifold (one projection), so
-queries are in-distribution.  Everything is float32, as served.
+A configuration's ``generator.kind`` names a file of its own,
+``bench/generators/<kind>.py``, whose ``make(cfg, seed)`` returns a
+:class:`Corpus`: the base rows, the query pool, and in ``meta`` whatever
+else the generator makes for the traffic and the judge (tags per row, a
+runbook of inserts and deletes).  A later change adds a generator as a new
+file.  Everything is float32, as served.
 """
 from __future__ import annotations
 
-import functools
+import dataclasses
+from pathlib import Path
 
 import jax
-import jax.numpy as jnp
 import numpy as np
+
+from bench import spec
+
+
+@dataclasses.dataclass
+class Corpus:
+    base: np.ndarray
+    pool: np.ndarray
+    meta: dict = dataclasses.field(default_factory=dict)
 
 
 def key_from_seed(seed: int):
@@ -26,36 +32,16 @@ def key_from_seed(seed: int):
     return jax.random.key(int(word))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("n", "dim", "intrinsic_dim", "noise"))
-def _manifold(key, *, n: int, dim: int, intrinsic_dim: int, noise: float):
-    k = intrinsic_dim
-    kz, kp, kn = jax.random.split(key, 3)
-    z = jax.random.normal(kz, (n, k), jnp.float32)
-    iu0, iu1 = np.triu_indices(k)
-    phi = jnp.concatenate([z, z[:, iu0] * z[:, iu1]], axis=1)
-    n_feat = phi.shape[1]
-    proj = jax.random.normal(kp, (n_feat, dim), jnp.float32) / np.sqrt(n_feat)
-    x = jnp.matmul(phi, proj, precision=jax.lax.Precision.HIGHEST)
-    return x + noise * jax.random.normal(kn, (n, dim), jnp.float32)
+def corpus(cfg: dict, seed: int, bench_dir: Path) -> Corpus:
+    """The configuration's corpus, from ``seed``, or from the generator's
+    own ``seed`` where the configuration fixes one (the run's seed then
+    orders the work, and does not change it); the generator is the one in
+    ``bench_dir``'s ``generators/``."""
+    return spec.generator(cfg["generator"]["kind"], bench_dir).make(cfg, seed)
 
 
-def planted_manifold(seed: int, n: int, dim: int, intrinsic_dim: int,
-                     noise: float) -> np.ndarray:
-    """(n, dim) float32 rows on the host, made on the default device."""
-    x = _manifold(key_from_seed(seed), n=n, dim=dim,
-                  intrinsic_dim=intrinsic_dim, noise=float(noise))
-    return np.asarray(x)
-
-
-def make_corpus(cfg: dict, seed: int) -> tuple[np.ndarray, np.ndarray]:
-    """(base rows, query pool) for a configuration, from ``seed``, or from
-    the generator's own ``seed`` where the configuration fixes one (the
-    run's seed then orders the work, and does not change it)."""
-    gen = cfg["generator"]
-    if gen["kind"] != "planted_manifold":
-        raise ValueError(f"unknown generator {gen['kind']!r}")
-    rows = planted_manifold(gen.get("seed", seed),
-                            cfg["n"] + cfg["query_pool"], cfg["dim"],
-                            gen["intrinsic_dim"], gen["noise"])
-    return rows[: cfg["n"]], rows[cfg["n"]:]
+def make_corpus(cfg: dict, seed: int, bench_dir: Path
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """(base rows, query pool) of :func:`corpus`."""
+    c = corpus(cfg, seed, bench_dir)
+    return c.base, c.pool

@@ -5,7 +5,8 @@ driver of a kind is a file of its own, ``bench/kinds/<kind>.py``, which
 :func:`load_kind` finds by that name, so a later change adds a kind as a
 new file and edits none.  A kind's file defines:
 
-* ``Driver(cfg, mix, seed, tracer_cfg)``, which runs one cell in phases:
+* ``Driver(cfg, mix, seed, tracer_cfg, bench_dir)``, which runs one cell
+  in phases:
   ``setup()`` (data from the seed, the index, and a warm-up of every
   program shape the mix will use, so that nothing compiles in the window);
   ``window(seconds) -> Run``, the measured stretch, driving the program's
@@ -13,9 +14,13 @@ new file and edits none.  A kind's file defines:
   answer due and reads what the metrics need; ``free()``, which drops the
   program's state; ``judge(run, answers) -> numbers`` against the plain
   references; and ``counts(run) -> (attempted, failed)``;
-* ``control_requests(cfg, mix, seed, seconds, requests, rows)``: the
-  requests a run of the kind judges, made from the seed as a run makes
-  them, as ``(base, queries, truth_ids, self_ids)`` (``bench/control.py``).
+* ``control_requests(cfg, mix, seed, seconds, requests, rows,
+  bench_dir)``: the requests a run of the kind judges, made from the seed
+  as a run makes them, as ``(base, queries, truth_ids, admissible)``, the
+  last the requests' admissible rows (``bench/reference.py``), or None
+  where every row is (``bench/control.py``);
+* ``CELL_KIND``: the suffix ``bench/spans.py`` gives its cells' span
+  readings (``serve``, ``explore``, ``build``).
 
 Here are the pieces kinds share: :class:`Run`, the record metric readers
 read; :class:`Tracer`; :class:`Ledger`, each request's stamps and answer;
@@ -28,7 +33,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import dataclasses
-import importlib.util
 import os
 import sys
 import time
@@ -36,7 +40,7 @@ from pathlib import Path
 
 import numpy as np
 
-from bench import data, judge
+from bench import data, judge, spec
 
 now = time.perf_counter
 
@@ -45,13 +49,8 @@ BENCH_DIR = Path(__file__).resolve().parent
 
 def load_kind(kind: str, bench_dir: Path = BENCH_DIR):
     """The module ``bench/kinds/<kind>.py``."""
-    path = Path(bench_dir) / "kinds" / f"{kind}.py"
-    if not path.exists():
-        raise KeyError(f"no traffic kind {kind!r}: {path} does not exist")
-    spec = importlib.util.spec_from_file_location(f"bench_kind_{kind}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return spec.load_file(Path(bench_dir) / "kinds" / f"{kind}.py",
+                          "bench_kind_")
 
 
 @dataclasses.dataclass
@@ -355,20 +354,24 @@ class SearchDriver:
     """A cell that searches an index built in set-up through
     ``AsyncQueryEngine``.  A kind fills in ``window`` (which files each
     future in ``self.ledger``) and ``judged()``: the queries of the
-    window's requests in send order, their exact neighbours, and the
-    vertex each must not return (or None)."""
+    window's requests in send order, their exact neighbours, and their
+    admissible rows (``bench/reference.py``), or None where every row
+    is.  ``bench_dir`` is the tree whose ``generators/`` holds the
+    configuration's generator."""
 
     #: the operands the bucket programs are warmed with: seeded queries
     #: with an exclude list, or plain ones
     explore = False
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, tracer_cfg):
+    def __init__(self, cfg: dict, mix: dict, seed: int, tracer_cfg,
+                 bench_dir: Path):
         self.cfg, self.mix, self.seed = cfg, mix, seed
-        self.tracer_cfg = tracer_cfg
+        self.tracer_cfg, self.bench_dir = tracer_cfg, bench_dir
 
     def setup(self) -> None:
         cfg = self.cfg
-        self.base, self.pool = data.make_corpus(cfg, self.seed)
+        c = data.corpus(cfg, self.seed, self.bench_dir)
+        self.base, self.pool, self.meta = c.base, c.pool, c.meta
         self.index = served_index(cfg, self.base)
         self.eng = engine(self.index, cfg, self.mix)
         warm_buckets(self.eng, self.index, self.explore)
@@ -396,10 +399,10 @@ class SearchDriver:
         del self.eng, self.index
 
     def judge(self, run: Run, ans: dict) -> dict:
-        q, truth, self_ids = self.judged()
+        q, truth, admissible = self.judged()
         nums = judge.answer_numbers(self.base, q, ans["ids"], ans["dists"],
                                     ans["ok"], truth, self.cfg["search"]["k"],
-                                    self_ids=self_ids)
+                                    admissible, self.cfg["metric"])
         run.recall = 1.0 - nums["recall_miss"]
         return nums
 

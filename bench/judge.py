@@ -9,7 +9,8 @@ Answers of a search (the serve and explore cells):
 
 * ``missing`` — requests due that never came back, or failed (exact, 0);
 * ``bad_rows`` — answers with an id outside the index, a repeated id,
-  distances out of order, or (exploration) the query's own vertex
+  distances out of order, or an id outside the request's admissible rows
+  (``reference.brute_force``), such as an exploration query's own vertex
   (exact, 0);
 * ``dist_err`` — the largest relative gap between a returned distance and
   the float64 distance of the returned id;
@@ -35,13 +36,14 @@ from bench import reference, stats
 
 def answer_numbers(base: np.ndarray, queries: np.ndarray, ids, dists,
                    answered: np.ndarray, truth_ids: np.ndarray, k: int,
-                   self_ids: np.ndarray | None = None) -> dict:
+                   admissible=None, metric: str = "l2") -> dict:
     """Numbers of a batch of search answers.
 
     ``ids``/``dists`` (Q, k) as served (rows of unanswered requests are
     ignored); ``answered`` (Q,) bool; ``truth_ids`` (Q, k) the exact
-    neighbours; ``self_ids`` (Q,) the vertex an exploration query must not
-    return, or None."""
+    neighbours; ``admissible`` the requests' admissible rows, as
+    ``reference.brute_force`` takes them, or None where every row is;
+    ``metric`` the configuration's."""
     answered = np.asarray(answered, bool)
     out = {"missing": int((~answered).sum())}
     q = np.asarray(queries)[answered]
@@ -56,12 +58,15 @@ def answer_numbers(base: np.ndarray, queries: np.ndarray, ids, dists,
     srt = np.sort(ids, axis=1)
     bad |= (srt[:, 1:] == srt[:, :-1]).any(axis=1)
     bad |= (np.diff(d, axis=1) < 0).any(axis=1) | ~np.isfinite(d).all(axis=1)
-    if self_ids is not None:
-        bad |= (ids == np.asarray(self_ids)[answered][:, None]).any(axis=1)
+    if admissible is not None:
+        inside = (ids >= 0) & (ids < n)
+        req = np.flatnonzero(answered)[:, None]
+        bad |= (inside & ~admissible(req, np.where(inside, ids, 0))
+                ).any(axis=1)
     out["bad_rows"] = int(bad.sum())
-    true_d = reference.exact_distances(q, base, ids)
+    true_d = reference.exact_distances(q, base, ids, metric)
     with np.errstate(invalid="ignore", divide="ignore"):
-        rel = np.abs(d - true_d) / np.maximum(true_d, 1e-30)
+        rel = np.abs(d - true_d) / np.maximum(np.abs(true_d), 1e-30)
     rel = np.where(np.isfinite(rel), rel, np.inf)
     out["dist_err"] = float(rel.max())
     out["recall_miss"] = float(1.0 - stats.recall_at_k(ids, truth, k).mean())

@@ -13,6 +13,8 @@ import numpy as np
 from bench import data, judge, reference
 from bench.drivers import Run, deg_params, now
 
+CELL_KIND = "build"
+
 
 def insert_order(seed: int, n: int) -> np.ndarray:
     return np.random.default_rng([seed, 3]).permutation(n)
@@ -20,9 +22,10 @@ def insert_order(seed: int, n: int) -> np.ndarray:
 
 class Driver:
 
-    def __init__(self, cfg: dict, mix: dict, seed: int, tracer_cfg):
+    def __init__(self, cfg: dict, mix: dict, seed: int, tracer_cfg,
+                 bench_dir):
         self.cfg, self.mix, self.seed = cfg, mix, seed
-        self.tracer_cfg = tracer_cfg
+        self.tracer_cfg, self.bench_dir = tracer_cfg, bench_dir
 
     def _chunk(self) -> None:
         m, i, idx = self.mix, self.inserted, self.index
@@ -47,7 +50,8 @@ class Driver:
         from repro.core.build import DEGIndex
 
         cfg = self.cfg
-        self.base, self.probe = data.make_corpus(cfg, self.seed)
+        self.base, self.probe = data.make_corpus(cfg, self.seed,
+                                                 self.bench_dir)
         self.rng = np.random.default_rng([self.seed, 4])
         self.order = insert_order(self.seed, len(self.base))
         self.index = DEGIndex(cfg["dim"], deg_params(cfg),
@@ -93,11 +97,14 @@ class Driver:
         nums = judge.build_numbers(ans["stored"], inserted, ans["adjacency"],
                                    self.cfg["deg"]["degree"])
         nums["refine_idle"] = judge.refine_idle(inserted, self.refines)
-        _, truth = reference.brute_force(self.probe, inserted, k)
+        metric = self.cfg["metric"]
+        _, truth = reference.brute_force(self.probe, inserted, k,
+                                         metric=metric)
         # the probe searches report ids as rows of the index, which are
         # the inserted rows in order
         nums.update(judge.answer_numbers(inserted, self.probe, ans["ids"],
-                                         ans["dists"], ans["ok"], truth, k))
+                                         ans["dists"], ans["ok"], truth, k,
+                                         metric=metric))
         run.built_recall = 1.0 - nums["recall_miss"]
         return nums
 
@@ -149,8 +156,9 @@ def warm_build(index, mix: dict) -> None:
 
 
 def control_requests(cfg: dict, mix: dict, seed: int, seconds: float,
-                     requests: int, rows: int):
-    base, pool = data.make_corpus(cfg, seed)
+                     requests: int, rows: int, bench_dir):
+    base, pool = data.make_corpus(cfg, seed, bench_dir)
     base = base[insert_order(seed, len(base))[:rows]]
-    _, truth = reference.brute_force(pool, base, cfg["search"]["k"])
+    _, truth = reference.brute_force(pool, base, cfg["search"]["k"],
+                                     metric=cfg["metric"])
     return base, pool, truth, None

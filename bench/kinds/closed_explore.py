@@ -8,6 +8,8 @@ import numpy as np
 from bench import data, reference
 from bench.drivers import Run, SearchDriver, now
 
+CELL_KIND = "explore"
+
 
 def vertex_pool(mix: dict, seed: int, n: int):
     """(the pool of vertices, the generator that draws from it)."""
@@ -15,12 +17,13 @@ def vertex_pool(mix: dict, seed: int, n: int):
     return rng.choice(n, size=min(mix["pool"], n), replace=False), rng
 
 
-def explore_truth(base: np.ndarray, picks: np.ndarray, k: int):
+def explore_truth(base: np.ndarray, picks: np.ndarray, k: int,
+                  metric: str):
     """The exact ``k`` nearest of each picked vertex, itself left out."""
     uniq, inv = np.unique(picks, return_inverse=True)
-    _, near = reference.brute_force(base[uniq], base, k + 1)
-    truth = np.stack([row[row != v][:k] for row, v in zip(near, uniq)])
-    return truth[inv]
+    _, near = reference.brute_force(base[uniq], base, k,
+                                    reference.all_but(uniq), metric)
+    return near[inv]
 
 
 class Driver(SearchDriver):
@@ -58,13 +61,15 @@ class Driver(SearchDriver):
     def judged(self):
         k = self.cfg["search"]["k"]
         return (self.base[self.picks],
-                explore_truth(self.base, self.picks, k), self.picks)
+                explore_truth(self.base, self.picks, k, self.cfg["metric"]),
+                reference.all_but(self.picks))
 
 
 def control_requests(cfg: dict, mix: dict, seed: int, seconds: float,
-                     requests: int, rows: int):
-    base, _ = data.make_corpus(cfg, seed)
+                     requests: int, rows: int, bench_dir):
+    base, _ = data.make_corpus(cfg, seed, bench_dir)
     verts, rng = vertex_pool(mix, seed, len(base))
     picks = verts[rng.integers(0, len(verts), size=requests)]
     return (base, base[picks],
-            explore_truth(base, picks, cfg["search"]["k"]), picks)
+            explore_truth(base, picks, cfg["search"]["k"], cfg["metric"]),
+            reference.all_but(picks))

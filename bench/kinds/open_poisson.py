@@ -11,6 +11,8 @@ import numpy as np
 from bench import data, reference
 from bench.drivers import Run, SearchDriver, now
 
+CELL_KIND = "serve"
+
 
 def schedule(mix: dict, seed: int, seconds: float, pool: int):
     """(due instants from the window's start, pool index of each query)."""
@@ -48,13 +50,15 @@ class Driver(SearchDriver):
 
     def judged(self):
         _, truth = reference.brute_force(self.pool, self.base,
-                                         self.cfg["search"]["k"])
+                                         self.cfg["search"]["k"],
+                                         metric=self.cfg["metric"])
         return self.pool[self.qi], truth[self.qi], None
 
 
 def control_requests(cfg: dict, mix: dict, seed: int, seconds: float,
-                     requests: int, rows: int):
-    base, pool = data.make_corpus(cfg, seed)
+                     requests: int, rows: int, bench_dir):
+    base, pool = data.make_corpus(cfg, seed, bench_dir)
     _, qi = schedule(mix, seed, seconds, len(pool))
-    _, truth = reference.brute_force(pool, base, cfg["search"]["k"])
+    _, truth = reference.brute_force(pool, base, cfg["search"]["k"],
+                                     metric=cfg["metric"])
     return base, pool[qi], truth[qi], None

@@ -130,7 +130,7 @@ def run(argv=None, bench_dir: Path = spec.BENCH_DIR,
             counters)
 
     kind = drivers.load_kind(mix["kind"], bench_dir)
-    drv = kind.Driver(cfg, mix, args.seed, tracer)
+    drv = kind.Driver(cfg, mix, args.seed, tracer, bench_dir)
     drv.setup()
     # the set-up heap (the index, JAX's caches) lives as long as the run:
     # freeze it, so that the collector, which stays on, does not walk it

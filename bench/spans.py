@@ -246,10 +246,6 @@ def readings(kind: str, table: dict, counters=None,
 # ---------------------------------------------------------------------------
 # the run: bench/run.py with spans on
 # ---------------------------------------------------------------------------
-KINDS = {"build_refine": "build", "open_poisson": "serve",
-         "closed_explore": "explore"}
-
-
 class SpanTracer(drivers.Tracer):
     """``drivers.Tracer`` that also keeps the span table at the host
     span's edges and reduces the trace's program spans; ``made`` holds
@@ -305,7 +301,7 @@ def main(argv=None) -> dict:
     bench = spec.load_benchmark()
     cell = spec.cell(bench, args["--workload"])
     cfg, mix = spec.config(cell["config"]), spec.mix(cell["traffic"])
-    kind = KINDS[mix["kind"]]
+    kind = drivers.load_kind(mix["kind"]).CELL_KIND
     rehearse = "--rehearse" in argv
 
     SpanTracer.made.clear()

@@ -5,7 +5,12 @@ metrics.  Everything that belongs to one configuration, one traffic mix or
 one metric sits in a file of its own, which this module finds by the name
 ``BENCHMARK.json`` gives:
 
-* a configuration: ``bench/configs/<config>.json``;
+* a configuration: ``bench/configs/<config>.json``, with its ``limits``,
+  its ``rehearsal`` sizes and its ``control`` sizes (those at which
+  ``bench/tests/test_bench_control.py`` runs the control of ``correct``);
+* a generator of data: ``bench/generators/<kind>.py``, named by a
+  configuration's ``generator.kind``; its ``make(cfg, seed)`` returns a
+  ``bench.data.Corpus``;
 * a traffic mix: ``bench/traffic/<mix>.json``, its parameters and the
   ``kind`` of driver that reads them;
 * a kind of driver: ``bench/kinds/<kind>.py`` (``bench/drivers.py``);
@@ -15,8 +20,9 @@ one metric sits in a file of its own, which this module finds by the name
   (``flush_ms.serve``, ``flush_ms.explore``), has one reader,
   ``bench/metrics/<quantity>.py``, where no reader of the full name exists.
 
-A later change adds a configuration, a mix or a metric by adding files and
-entries, and edits none of the files that are there.
+A later change adds a configuration, a generator, a mix, a kind or a
+metric by adding files and entries, and edits none of the files that are
+there.
 """
 from __future__ import annotations
 
@@ -75,14 +81,27 @@ def reader_path(name: str, bench_dir: Path = BENCH_DIR) -> Path:
     return path
 
 
-def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
-    """The ``read(run)`` function of the metric's reader."""
-    path = reader_path(name, bench_dir)
-    mod_name = "bench_metric_" + path.stem.replace(".", "_").replace("-", "_")
-    spec = importlib.util.spec_from_file_location(mod_name, path)
+def load_file(path: Path, prefix: str):
+    """The module in the file ``path``, loaded under a name of its own."""
+    path = Path(path)
+    if not path.exists():
+        raise KeyError(f"{path} does not exist")
+    name = prefix + path.stem.replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR):
+    """The ``read(run)`` function of the metric's reader."""
+    return load_file(reader_path(name, bench_dir), "bench_metric_").read
+
+
+def generator(kind: str, bench_dir: Path = BENCH_DIR):
+    """The module ``bench/generators/<kind>.py``."""
+    return load_file(bench_dir / "generators" / f"{kind}.py",
+                     "bench_generator_")
 
 
 def cell(bench: dict, workload: str) -> dict:

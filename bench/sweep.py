@@ -51,7 +51,8 @@ def main(argv=None) -> int:
     cfg, mix = spec.config(cell["config"]), spec.mix(cell["traffic"])
     kind = drivers.load_kind(mix["kind"])
     drv = kind.Driver(cfg, mix, args.seed,
-                      lambda c: drivers.Tracer(False, 0, 0, c))
+                      lambda c: drivers.Tracer(False, 0, 0, c),
+                      spec.BENCH_DIR)
     drv.setup()
     gc.collect()                    # as bench/run.py does for its window
     gc.freeze()

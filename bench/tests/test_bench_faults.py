@@ -34,3 +34,12 @@ def test_a_refinement_that_changes_nothing_is_not_correct():
     assert not out["correct"], out["checks"]
     c = out["checks"]["refine_idle"]
     assert c["value"] > c["limit"], out["checks"]
+
+
+def test_an_altered_answer_of_a_one_request_flush_is_not_correct():
+    """The interactive cell's flushes hold one request each, so half of a
+    flush cannot be left out; an altered answer can."""
+    with faults.planted("answer_altered"):
+        out = run.run(["--workload", "audio.interactive"] + ARGS)
+    assert out["attempted"] > 0
+    assert not out["correct"], out["checks"]

@@ -8,7 +8,7 @@ import time
 import jax
 import pytest
 
-from bench import spans, spec
+from bench import drivers, spans, spec
 from bench import trace_reduce as tr
 from bench.tests.test_bench_trace_reduce import chip_slice
 from repro.obs import trace
@@ -149,8 +149,8 @@ CELLS = [w["name"] for w in spec.load_benchmark()["workloads"]]
 
 @pytest.mark.parametrize("workload", CELLS)
 def test_a_rehearsal_with_spans_reads_every_span_reading(workload, capsys):
-    kind = spans.KINDS[spec.mix(spec.cell(spec.load_benchmark(),
-                                          workload)["traffic"])["kind"]]
+    kind = drivers.load_kind(spec.mix(spec.cell(
+        spec.load_benchmark(), workload)["traffic"])["kind"]).CELL_KIND
     out = spans.main(["--workload", workload, "--seed", str(2**31 + 7),
                       "--seconds", "6", "--trace", "1", "--rehearse"])
     assert not trace.enabled()
