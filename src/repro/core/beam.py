@@ -39,6 +39,12 @@ stack.
 Exploration queries (paper Sec. 6.7) are native: seeds may be graph
 vertices and ``exclude`` removes vertices from the *result list* (and from
 the radius ``r``) while still allowing navigation through them.
+
+Filtered queries (``core/tags.py``) are too: with a ``filter_tags``
+operand the beam keeps a second sorted list per lane, its results, which
+only vertices carrying every tag of the lane's filter enter; the radius is
+that list's k-th distance, and every vertex stays navigable.  Without the
+operand nothing of it is traced.
 """
 from __future__ import annotations
 
@@ -78,6 +84,13 @@ class BeamState:
     # final state: every one of the B lanes runs each trip, so
     # trips * B lane-trips against sum(hops) is the lock-step waste
     trips: Optional[Array] = None
+    # filtered search: the lane's results (B, R), sorted, INVALID / inf
+    # padded — admitted, non-excluded vertices only; their k-th distance
+    # is the radius — and (B,) the distance evaluations of vertices the
+    # filter did not admit.  None without a filter.
+    res_ids: Optional[Array] = None
+    res_dists: Optional[Array] = None
+    rejected: Optional[Array] = None
 
     @property
     def width(self) -> int:
@@ -104,8 +117,37 @@ def in_set(ids: Array, excl: Array) -> Array:
     return hit & (ids != INVALID)
 
 
+def admitted(tags: Array, ids: Array, filter_tags: Array) -> Array:
+    """ids (B, C) -> bool (B, C): the vertex carries every tag of its
+    lane's filter.  ``tags`` is the (capacity, T) tag table,
+    ``filter_tags`` (B, F) the lanes' filters; an INVALID filter slot asks
+    for nothing, an INVALID id is never admitted."""
+    rows = tags[jnp.where(ids == INVALID, 0, ids)]            # (B, C, T)
+    has = (rows[:, :, None, :] == filter_tags[:, None, :, None]).any(axis=3)
+    has |= (filter_tags == INVALID)[:, None, :]
+    return has.all(axis=2) & (ids != INVALID)
+
+
+def fold_results(res_ids: Array, res_dists: Array, cand_ids: Array,
+                 cand_dists: Array, cand_ok: Array) -> tuple[Array, Array]:
+    """The sorted result list (B, R) with the ``cand_ok`` candidates
+    folded in; the R best stay (``top_k`` keeps the first of equal
+    distances, so a tie keeps list order)."""
+    R = res_ids.shape[1]
+    d = jnp.concatenate([res_dists, jnp.where(cand_ok, cand_dists, _INF)],
+                        axis=1)
+    ids = jnp.concatenate([res_ids, jnp.where(cand_ok, cand_ids, INVALID)],
+                          axis=1)
+    neg, order = jax.lax.top_k(-d, R)
+    ids = jnp.take_along_axis(ids, order, axis=1)
+    return jnp.where(jnp.isinf(neg), INVALID, ids), -neg
+
+
 def radius(state: BeamState, k: int) -> Array:
-    """k-th best non-excluded distance per lane (inf if fewer than k)."""
+    """k-th best non-excluded distance per lane (inf if fewer than k); in
+    a filtered search, the k-th admitted one."""
+    if state.res_dists is not None:
+        return state.res_dists[:, k - 1]
     ok = (state.ids != INVALID) & ~state.excluded
     cnt = jnp.cumsum(ok.astype(jnp.int32), axis=1)
     at_k = ok & (cnt == k)
@@ -119,13 +161,17 @@ def radius(state: BeamState, k: int) -> Array:
 # ---------------------------------------------------------------------------
 def init(vectors: Array | VectorStore, queries: Array, seed_ids: Array,
          exclude: Array, n_valid: Array, *, beam_width: int,
-         metric: str, visited_size: int = 0) -> BeamState:
+         metric: str, visited_size: int = 0,
+         tags: Optional[Array] = None, filter_tags: Optional[Array] = None,
+         res_width: int = 0) -> BeamState:
     """Seed the beam: dedup seeds per lane, score them, sort, pad to L.
 
     ``visited_size > 0`` additionally allocates the per-lane visited hash
     set (that many slots, power of two) and records the seeds in it —
     :func:`expand` then uses it for the per-hop dedup instead of the beam
-    broadcast."""
+    broadcast.  ``filter_tags`` (B, F) starts a filtered search over the
+    ``tags`` table with a result list of ``res_width`` entries, which the
+    admitted seeds enter."""
     B = queries.shape[0]
     L = beam_width
     store = as_store(vectors)
@@ -153,6 +199,15 @@ def init(vectors: Array | VectorStore, queries: Array, seed_ids: Array,
         vis = visited_set.make_table(B, visited_size)
         vis = visited_set.insert(vis, seed_ids_m, seed_valid)
 
+    res_ids = res_dists = rejected = None
+    if filter_tags is not None:
+        adm = admitted(tags, seed_ids_m, filter_tags)
+        rejected = (seed_valid & ~adm).sum(axis=1).astype(jnp.int32)
+        res_ids, res_dists = fold_results(
+            jnp.full((B, res_width), INVALID, jnp.int32),
+            jnp.full((B, res_width), _INF), seed_ids_m, seed_d,
+            adm & ~in_set(seed_ids_m, exclude))
+
     order = jnp.argsort(dists, axis=1)
     take = functools.partial(jnp.take_along_axis, indices=order, axis=1)
     return BeamState(
@@ -160,7 +215,8 @@ def init(vectors: Array | VectorStore, queries: Array, seed_ids: Array,
         excluded=take(excl),
         hops=jnp.zeros((B,), jnp.int32),
         evals=seed_valid.sum(axis=1).astype(jnp.int32),
-        visited=vis)
+        visited=vis, res_ids=res_ids, res_dists=res_dists,
+        rejected=rejected)
 
 
 def merge(state: BeamState, cand_ids: Array, cand_dists: Array,
@@ -222,7 +278,9 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
            eps: float, metric: str, backend: str = "jnp",
            merge_backend: str = "jnp", expand_width: int = 1,
            hop_backend: str = "jnp",
-           hop_budget: Optional[Array] = None) -> BeamState:
+           hop_budget: Optional[Array] = None,
+           tags: Optional[Array] = None,
+           filter_tags: Optional[Array] = None) -> BeamState:
     """One hop: expand each lane's ``expand_width`` closest unchecked
     entries (Alg. 1 lines 8-15, generalized to a multi-expansion frontier)
     and merge their scored neighbors into the beam in one pass.
@@ -239,7 +297,10 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
     deadline early-extract).  ``None`` (the default) is the unbudgeted
     program, bit for bit.  With ``expand_width > 1`` a lane may overshoot
     its budget by up to E-1 expansions (the E selections of one hop are
-    committed together)."""
+    committed together).
+
+    ``filter_tags`` (B, F), with the ``tags`` table, folds the hop's
+    admitted candidates into the state's result list (:func:`init`)."""
     B, L = state.ids.shape
     E = expand_width
     d = adjacency.shape[1]
@@ -275,6 +336,7 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
         cand_exc = in_set(cand_ids, exclude) & (cand_ids != INVALID)
         new_visited = visited_set.insert(state.visited, nbr_out,
                                          nbr_out != INVALID)
+        scored = lambda: nbr_out                                  # noqa: E731
     else:
         nbrs = adjacency[jnp.where(active, sel_id, 0)]       # (B, E, d)
         valid = active[:, :, None] & (nbrs != INVALID) & (nbrs < n_valid)
@@ -307,6 +369,25 @@ def expand(state: BeamState, adjacency: Array, n_valid: Array,
         evals_inc = ok.sum(axis=1).astype(jnp.int32)
         new_visited = (visited_set.insert(state.visited, flat, ok)
                        if use_visited else state.visited)
+        scored = lambda: jnp.where(ok, flat, INVALID)             # noqa: E731
+
+    if filter_tags is not None:
+        # a kept candidate that carries the filter's tags is a result
+        # candidate; the rest stay navigable only (the ids a hop scored
+        # are built here, so the unfiltered program traces none of it)
+        scored = scored()
+        adm_scored = admitted(tags, scored, filter_tags)
+        if hop_backend == "pallas":         # candidates compacted
+            adm = admitted(tags, cand_ids, filter_tags)
+        else:                               # candidates where scored ones
+            adm = adm_scored & (cand_ids != INVALID)
+        res_ids, res_dists = fold_results(
+            state.res_ids, state.res_dists, cand_ids, cand_d,
+            adm & ~cand_exc & ~in_set(cand_ids, state.res_ids))
+        rejected = state.rejected + ((scored != INVALID) & ~adm_scored
+                                     ).sum(axis=1).astype(jnp.int32)
+        state = dataclasses.replace(state, res_ids=res_ids,
+                                    res_dists=res_dists, rejected=rejected)
 
     state = dataclasses.replace(
         state, checked=checked,
@@ -341,7 +422,10 @@ def extract(state: BeamState, k: int, *, dedup: bool = False
     position, matching ``search.exact_rerank`` tie semantics.  ``dedup``
     masks repeated ids (keeping the first occurrence) — the safety net for
     visited-filter searches, where a dropped hash insert can in principle
-    let a vertex enter the beam twice."""
+    let a vertex enter the beam twice.  A filtered search returns its
+    result list, which holds no id twice."""
+    if state.res_ids is not None:
+        return state.res_ids[:, :k], state.res_dists[:, :k]
     final_d = jnp.where(state.excluded | (state.ids == INVALID), _INF,
                         state.dists)
     if dedup:
@@ -365,7 +449,10 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
                 merge_backend: str = "jnp", expand_width: int = 1,
                 visited_size: int = 0,
                 hop_backend: str = "jnp",
-                hop_budget: Optional[Array] = None) -> BeamState:
+                hop_budget: Optional[Array] = None,
+                tags: Optional[Array] = None,
+                filter_tags: Optional[Array] = None,
+                res_width: int = 0) -> BeamState:
     """init -> while(expand) -> final BeamState, its ``trips`` the loop's
     trip count.  Pure (un-jitted): callers
     embed it in their own jitted programs (``range_search``, the sharded
@@ -384,7 +471,11 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
     ``hop_budget`` (B,) int32 per-lane expansion caps (serving early
     extract): a budget-exhausted lane stops hopping and its final beam is
     its best-so-far answer.  ``None`` = unbudgeted (the golden program —
-    the budget branch is not even traced)."""
+    the budget branch is not even traced).
+
+    ``filter_tags`` (B, F) with the (capacity, T) ``tags`` table: a
+    filtered search whose results, ``res_width`` of them, carry every tag
+    of their lane's filter (``None``: the unfiltered program, untouched)."""
     if expand_width < 1:
         raise ValueError(f"expand_width must be >= 1, got {expand_width}")
     expand_width = min(expand_width, beam_width)
@@ -399,7 +490,8 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
 
     state0 = init(vectors, queries, seed_ids, exclude, n_valid,
                   beam_width=beam_width, metric=metric,
-                  visited_size=visited_size)
+                  visited_size=visited_size, tags=tags,
+                  filter_tags=filter_tags, res_width=res_width)
 
     def cond(carry):
         _, it, any_alive = carry
@@ -411,7 +503,8 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
                        k=k, eps=eps, metric=metric, backend=backend,
                        merge_backend=merge_backend,
                        expand_width=expand_width, hop_backend=hop_backend,
-                       hop_budget=hop_budget)
+                       hop_budget=hop_budget, tags=tags,
+                       filter_tags=filter_tags)
         return (state, it + 1,
                 alive(state, k=k, eps=eps, hop_budget=hop_budget).any())
 
@@ -422,7 +515,7 @@ def beam_search(graph: DEGraph, vectors: Array | VectorStore, queries: Array,
 
 # jitted standalone primitives (library surface for out-of-loop callers)
 init_jit = jax.jit(init, static_argnames=("beam_width", "metric",
-                                          "visited_size"))
+                                          "visited_size", "res_width"))
 merge_jit = jax.jit(merge, static_argnames=("merge_backend",))
 expand_jit = jax.jit(
     expand, static_argnames=("k", "metric", "backend", "merge_backend",

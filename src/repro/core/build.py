@@ -37,7 +37,9 @@ from .distances import get_metric
 from .graph import (DEGraph, GraphBuilder, INVALID, complete_graph,
                     pow2_bucket)
 from .mrng import check_mrng_candidate
-from .search import SearchResult, medoid_seed, range_search, table_format
+from .search import (SearchResult, filtered_scan, medoid_seed, range_search,
+                     table_format)
+from .tags import TagIndex, tag_rows
 
 
 # ---------------------------------------------------------------------------
@@ -235,6 +237,12 @@ class DEGIndex:
         self.quarantine: set[int] = set()
         self._publish_every_chunks = 0
         self._refine_chunk_counter = 0
+        # row tags (core/tags.py), None until rows are added with tags:
+        # the host table (capacity, T), its device twin and its posting
+        # lists, the latter two made when first read after a change
+        self.tags: Optional[np.ndarray] = None
+        self._dev_tags: Optional[jax.Array] = None
+        self._tag_index: Optional[TagIndex] = None
 
     # -- sizes -------------------------------------------------------------
     @property
@@ -255,6 +263,12 @@ class DEGIndex:
         self._dev_vectors = self._table.copy(vecs)
         self._placed_table()
         self._stores = {}
+        if self.tags is not None:
+            tags = np.full((new_capacity, self.tags.shape[1]), INVALID,
+                           np.int32)
+            tags[: len(self.tags)] = self.tags
+            self.tags = tags
+            self._dev_tags = None
         if self.builder is not None:
             self.builder.grow(new_capacity)
 
@@ -298,6 +312,56 @@ class DEGIndex:
         if self._medoid is None or self._medoid >= self.n:
             self._medoid = medoid_seed(self._dev_vectors, self.n)
         return self._medoid
+
+    # -- row tags (core/tags.py) ---------------------------------------------
+    @property
+    def tagged(self) -> bool:
+        """Whether rows carry tags, so that filtered searches can run."""
+        return self.tags is not None
+
+    def _put_tags(self, tags, start: int, rows: int, slots: int) -> None:
+        """Write a CSR tag list for the ``rows`` rows from ``start``."""
+        indptr, indices = tags
+        if len(indptr) != rows + 1:
+            raise ValueError(f"tags list {len(indptr) - 1} rows for "
+                             f"{rows} points")
+        if self.tags is None:
+            self.tags = np.full((self.capacity, slots), INVALID, np.int32)
+        self.tags[start: start + rows] = tag_rows(indptr, indices,
+                                                  self.tags.shape[1])
+        self._tags_changed()
+
+    def _move_tags(self, src: int, dst: int) -> None:
+        """Deletion's compaction moved row ``src`` into slot ``dst``."""
+        if self.tags is not None:
+            self.tags[dst] = self.tags[src]
+            self.tags[src] = INVALID
+            self._tags_changed()
+
+    def _tags_changed(self) -> None:
+        self._dev_tags = None
+        self._tag_index = None
+
+    def device_tags(self) -> jax.Array:
+        """The (capacity, T) int32 tag table on the device."""
+        if self._dev_tags is None:
+            self._dev_tags = jnp.asarray(self.tags)
+        return self._dev_tags
+
+    def tag_index(self) -> TagIndex:
+        """Posting lists of the indexed rows' tags."""
+        ti = self._tag_index
+        if ti is None or len(ti.table) != self.n:
+            ti = self._tag_index = TagIndex(self.tags[: self.n].copy())
+        return ti
+
+    def filter_rows(self, filt) -> np.ndarray:
+        """The indexed rows ``filt`` admits (core/tags.py), ascending."""
+        return self.tag_index().rows(filt)
+
+    def filter_count(self, filt) -> int:
+        """How many indexed rows ``filt`` admits."""
+        return self.tag_index().count(filt)
 
     def frozen(self) -> DEGraph:
         """The device twin consumed transiently by every search call —
@@ -364,12 +428,15 @@ class DEGIndex:
                                  {"epoch": int(e), "n": int(self.n),
                                   "gen": int(gen),
                                   "quarantine": [int(q) for q in quar]}, {})
+            tagged = self.tags is not None
             ep = PublishedEpoch(
                 epoch=e, graph=self.builder.freeze(),
                 vectors=self._table.copy(self._placed_table()), n=self.n,
                 medoid_id=self._publish_medoid(quar),
                 metric=self.params.metric, params=self.params,
-                quarantine=quar, builder_gen=gen)
+                quarantine=quar, builder_gen=gen,
+                tags=self.device_tags() if tagged else None,
+                tag_index=self.tag_index() if tagged else None)
             _faults.fire("publish.swap", epoch=e, n=self.n)
             self._epochs.publish(ep)
         if self.metrics is not None:
@@ -417,8 +484,23 @@ class DEGIndex:
 
     # -- insertion -----------------------------------------------------------
     @_locked
-    def add(self, points: np.ndarray, wave_size: int = 1) -> None:
-        """Insert points (Alg. 3). ``wave_size>1`` enables bulk build."""
+    def add(self, points: np.ndarray, wave_size: int = 1, tags=None,
+            tag_slots: int = 16) -> None:
+        """Insert points (Alg. 3). ``wave_size>1`` enables bulk build.
+
+        ``tags`` gives the points' tags as a CSR list ``(indptr,
+        indices)`` (core/tags.py); the first tagged add sizes the tag
+        table at ``tag_slots`` tags a row.  Rows added without tags carry
+        none."""
+        if tags is not None:
+            if self._wal is not None:
+                raise NotImplementedError("the WAL does not journal tags")
+            pts = np.asarray(points)
+            start = self.n + len(self._pending)
+            rows = 1 if pts.ndim == 1 else len(pts)
+            if start + rows > self.capacity:
+                self.grow(max(2 * self.capacity, start + rows))
+            self._put_tags(tags, start, rows, tag_slots)
         with span("deg.add"):
             self._add(points, wave_size)
 
@@ -769,6 +851,8 @@ class DEGIndex:
         immediately mutable index."""
         from repro.persist import save_index
 
+        if self.tags is not None:
+            raise NotImplementedError("snapshots do not carry row tags")
         save_index(self, path)
 
     @classmethod
@@ -857,7 +941,9 @@ class DEGIndex:
                      expand_width: Optional[int] = None,
                      visited_size: Optional[int] = None,
                      hop_backend: Optional[str] = None,
-                     hop_budget: Optional[np.ndarray] = None) -> SearchResult:
+                     hop_budget: Optional[np.ndarray] = None,
+                     filter_tags: Optional[np.ndarray] = None
+                     ) -> SearchResult:
         """The one device entry point every query path funnels through.
 
         ``seed_ids`` (B, S) / ``exclude`` (B, X) go straight into the beam
@@ -879,6 +965,9 @@ class DEGIndex:
         ``hop_budget`` (B,) int32 per-lane expansion caps (serving
         deadline early-extract; a traced operand, so all budget values
         share one compiled program per shape family).
+
+        ``filter_tags`` (B, F) int32, INVALID-padded: each lane returns
+        only rows carrying every tag of its filter (core/tags.py).
         """
         E = self.params.expand_width if expand_width is None else expand_width
         hb = self.params.hop_backend if hop_backend is None else hop_backend
@@ -894,13 +983,16 @@ class DEGIndex:
             np.asarray(exclude, np.int32))
         hbud = None if hop_budget is None else jnp.asarray(
             np.asarray(hop_budget, np.int32))
+        filt = {} if filter_tags is None else {
+            "tags": self.device_tags(),
+            "filter_tags": jnp.asarray(np.asarray(filter_tags, np.int32))}
         if quantized in (None, "float32"):
             return range_search(self.frozen(), self._dev_vectors, q, seeds,
                                 k=k, eps=eps, beam_width=beam_width,
                                 metric=self.params.metric, exclude=excl,
                                 backend=backend, expand_width=E,
                                 visited_size=vs, hop_backend=hb,
-                                hop_budget=hbud)
+                                hop_budget=hbud, **filt)
         store = self.store_for(quantized)
         rk = int(rerank_k) if rerank_k else 4 * k
         return range_search(self.frozen(), store, q, seeds, k=k, eps=eps,
@@ -909,7 +1001,18 @@ class DEGIndex:
                             backend=backend, rerank_k=max(rk, k),
                             exact_vectors=self._dev_vectors, expand_width=E,
                             visited_size=vs, hop_backend=hb,
-                            hop_budget=hbud)
+                            hop_budget=hbud, **filt)
+
+    def scan_batch(self, queries: np.ndarray, *chunks, k: int
+                   ) -> SearchResult:
+        """Exact k-NN of each query among its own rows, given in chunks
+        (``core/search.py::filtered_scan``, ``serving/buckets.py::
+        pad_scan``)."""
+        return filtered_scan(self._dev_vectors,
+                             jnp.asarray(np.asarray(queries, np.float32)),
+                             *(jnp.asarray(np.asarray(c, np.int32))
+                               for c in chunks),
+                             k=k, metric=self.params.metric)
 
     def search(self, queries: np.ndarray, k: int, eps: float = 0.1,
                beam_width: Optional[int] = None, seed: Optional[int] = None,
@@ -972,12 +1075,15 @@ class DEGIndex:
 
 def build_deg(vectors: np.ndarray, params: DEGParams | None = None,
               wave_size: int = 1, refine_iterations: int = 0,
-              capacity: Optional[int] = None) -> DEGIndex:
-    """One-shot construction of a DEG over ``vectors``."""
+              capacity: Optional[int] = None, tags=None,
+              tag_slots: int = 16) -> DEGIndex:
+    """One-shot construction of a DEG over ``vectors``; ``tags``, a CSR
+    list ``(indptr, indices)`` of each row's tags, makes filtered searches
+    possible (``DEGIndex.add``).  The graph is built without them."""
     vectors = np.asarray(vectors, dtype=np.float32)
     idx = DEGIndex(vectors.shape[1], params,
                    capacity=capacity or vectors.shape[0])
-    idx.add(vectors, wave_size=wave_size)
+    idx.add(vectors, wave_size=wave_size, tags=tags, tag_slots=tag_slots)
     if refine_iterations:
         idx.refine(refine_iterations)
     return idx

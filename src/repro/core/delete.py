@@ -154,6 +154,7 @@ def delete_vertex(index: DEGIndex, v: int, *, rng=None,
         for u, w in zip(last_nbrs, last_ws):
             b.add_edge(v, u if u != v else last, w)
     b.clear_vertex(last)           # marks the row dirty for the device sync
+    index._move_tags(last, v)      # slot v takes the last row's tags
     b.n -= 1
 
     # quarantine ids (scrubber state) track the compaction remap: the

@@ -38,7 +38,7 @@ import numpy as np
 from repro.obs import clock
 from repro.obs.metrics import EPOCH_RETIRED_LAG_MS
 
-from .search import SearchResult, range_search
+from .search import SearchResult, filtered_scan, range_search
 
 
 class PublishedEpoch:
@@ -52,10 +52,12 @@ class PublishedEpoch:
 
     __slots__ = ("epoch", "graph", "vectors", "n", "medoid_id", "metric",
                  "params", "quarantine", "builder_gen", "published_at",
-                 "superseded_at", "refs", "_stores", "_lock")
+                 "superseded_at", "refs", "tags", "tag_index", "_stores",
+                 "_lock")
 
     def __init__(self, *, epoch: int, graph, vectors, n: int, medoid_id: int,
-                 metric: str, params, quarantine=(), builder_gen: int = -1):
+                 metric: str, params, quarantine=(), builder_gen: int = -1,
+                 tags=None, tag_index=None):
         self.epoch = int(epoch)
         self.graph = graph               # independent DEGraph (freeze())
         self.vectors = vectors           # independent device copy
@@ -68,6 +70,10 @@ class PublishedEpoch:
         self.published_at = clock.now()
         self.superseded_at: Optional[float] = None
         self.refs = 0                    # guarded by the owning manager
+        # row tags (core/tags.py): the device table and the host posting
+        # lists, both immutable, or None for an index without tags
+        self.tags = tags
+        self.tag_index = tag_index
         self._stores: dict = {}          # per-epoch quant stores, lazy
         self._lock = threading.Lock()
 
@@ -77,6 +83,26 @@ class PublishedEpoch:
 
     def medoid(self) -> int:
         return self.medoid_id
+
+    @property
+    def tagged(self) -> bool:
+        return self.tags is not None
+
+    def filter_rows(self, filt) -> np.ndarray:
+        """The rows of this epoch ``filt`` admits, ascending."""
+        return self.tag_index.rows(filt)
+
+    def filter_count(self, filt) -> int:
+        """How many rows of this epoch ``filt`` admits."""
+        return self.tag_index.count(filt)
+
+    def scan_batch(self, queries, *chunks, k: int) -> SearchResult:
+        """Mirror of ``DEGIndex.scan_batch`` over this epoch's rows."""
+        return filtered_scan(self.vectors,
+                             jnp.asarray(np.asarray(queries, np.float32)),
+                             *(jnp.asarray(np.asarray(c, np.int32))
+                               for c in chunks),
+                             k=k, metric=self.metric)
 
     def store_for(self, codec: str):
         """Quant store over *this epoch's* vectors (lazy, cached for the
@@ -94,7 +120,7 @@ class PublishedEpoch:
                      eps: float = 0.1, beam_width=None, backend: str = "jnp",
                      quantized=None, rerank_k=None, expand_width=None,
                      visited_size=None, hop_backend=None,
-                     hop_budget=None) -> SearchResult:
+                     hop_budget=None, filter_tags=None) -> SearchResult:
         """Mirror of ``DEGIndex.search_batch`` against this epoch's frozen
         buffers.  Shapes and static config match the live index's, so the
         jitted beam program is shared — publishing costs no retrace."""
@@ -114,13 +140,16 @@ class PublishedEpoch:
             np.asarray(exclude, np.int32))
         hbud = None if hop_budget is None else jnp.asarray(
             np.asarray(hop_budget, np.int32))
+        filt = {} if filter_tags is None else {
+            "tags": self.tags,
+            "filter_tags": jnp.asarray(np.asarray(filter_tags, np.int32))}
         if quantized in (None, "float32"):
             return range_search(self.graph, self.vectors, q, seeds, k=k,
                                 eps=eps, beam_width=beam_width,
                                 metric=self.metric, exclude=excl,
                                 backend=backend, expand_width=E,
                                 visited_size=vs, hop_backend=hb,
-                                hop_budget=hbud)
+                                hop_budget=hbud, **filt)
         store = self.store_for(quantized)
         rk = int(rerank_k) if rerank_k else 4 * k
         return range_search(self.graph, store, q, seeds, k=k, eps=eps,
@@ -128,7 +157,7 @@ class PublishedEpoch:
                             exclude=excl, backend=backend,
                             rerank_k=max(rk, k), exact_vectors=self.vectors,
                             expand_width=E, visited_size=vs, hop_backend=hb,
-                            hop_budget=hbud)
+                            hop_budget=hbud, **filt)
 
 
 class EpochManager:

@@ -99,6 +99,9 @@ class SearchResult:
     # () int32 — the beam loop's trip count (BeamState.trips): each trip
     # runs all B lanes, finished and padded ones included
     trips: Optional[Array] = None
+    # (B,) int32 — a filtered search's distance evaluations of vertices
+    # its filter did not admit (BeamState.rejected); None unfiltered
+    rejected: Optional[Array] = None
 
 
 def exact_rerank(exact_vectors: Array, queries: Array, cand_ids: Array,
@@ -148,6 +151,8 @@ def range_search(
     visited_size: Optional[int] = None,
     hop_backend: str = "jnp",
     hop_budget: Optional[Array] = None,
+    tags: Optional[Array] = None,
+    filter_tags: Optional[Array] = None,
 ) -> SearchResult:
     """Approximate k-NN for a batch of queries.
 
@@ -189,6 +194,12 @@ def range_search(
         stops hopping and returns its best-so-far beam (a traced operand,
         so every budget value shares one compiled program; ``None`` keeps
         the unbudgeted golden program).
+      tags: (capacity, T) int32 tag table (``core/tags.py``), read only
+        with ``filter_tags``.
+      filter_tags: optional (B, F) int32 filter per lane, INVALID-padded:
+        only vertices carrying each of its tags are returned (and set the
+        radius); every vertex stays navigable.  ``None`` keeps the
+        unfiltered program, which then has no tag operand.
     """
     n_ex = exclude.shape[1] if exclude is not None else 0
     L = (beam_width if beam_width is not None
@@ -217,7 +228,8 @@ def range_search(
         max_hops=max_hops, metric=metric, exclude=exclude, backend=backend,
         merge_backend=merge_backend, expand_width=expand_width,
         visited_size=visited_size, hop_backend=hop_backend,
-        hop_budget=hop_budget)
+        hop_budget=hop_budget, tags=tags, filter_tags=filter_tags,
+        res_width=max(k, rerank_k) if filter_tags is not None else 0)
     if rerank_k:
         cand_ids, _ = beam.extract(state, rerank_k, dedup=dedup)
         out_ids, out_d = exact_rerank(exact_vectors, queries, cand_ids,
@@ -233,7 +245,46 @@ def range_search(
                                 .astype(jnp.float32), axis=1)
     return SearchResult(ids=out_ids, dists=out_d, hops=state.hops,
                         evals=evals, visited_frac=visited_frac,
-                        trips=state.trips)
+                        trips=state.trips, rejected=state.rejected)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "metric"))
+def filtered_scan(vectors: Array, queries: Array, rows: Array,
+                  chunk_lane: Array, lane_chunks: Array, *, k: int,
+                  metric: str = "l2") -> SearchResult:
+    """Exact k-NN of each query among its own rows: the route of a
+    filtered search whose filter admits few rows (``serving/buckets.py``).
+
+    The lanes' admitted rows come in chunks, ``rows`` (NC, C) int32, each
+    lane's ascending in chunks of its own, INVALID-padded; ``chunk_lane``
+    (NC,) is the row of ``queries`` (B, m) a chunk belongs to, and
+    ``lane_chunks`` (B, J) the chunks of each lane in order, NC where it
+    has fewer than J.  Each row of ``vectors`` (the exact float table) is
+    scored against its lane's query as the beam scores it; each chunk's k
+    nearest, then each lane's k nearest of its chunks', are taken with
+    ``top_k``, which keeps the first of equal distances, so ties go to the
+    lower row id.  Chunks keep every shape small: a sort of one flat
+    array compiles slowly on a TPU.  ``evals`` counts a lane's rows; there
+    are no hops."""
+    B = queries.shape[0]
+    NC = rows.shape[0]
+    valid = rows != INVALID
+    q = queries[jnp.minimum(chunk_lane, B - 1)]
+    d = get_metric(metric).pair(q[:, None, :],
+                                vectors[jnp.where(valid, rows, 0)])
+    neg, pos = jax.lax.top_k(-jnp.where(valid, d, jnp.inf), k)
+    chunk_ids = jnp.take_along_axis(rows, pos, axis=1)
+    has = lane_chunks < NC
+    at = jnp.where(has, lane_chunks, 0)
+    lane_neg = jnp.where(has[:, :, None], neg[at], -jnp.inf).reshape(B, -1)
+    lane_ids = chunk_ids[at].reshape(B, -1)
+    neg, pos = jax.lax.top_k(lane_neg, k)
+    out_d = -neg
+    out_ids = jnp.take_along_axis(lane_ids, pos, axis=1)
+    evals = jnp.where(has, valid.sum(axis=1, dtype=jnp.int32)[at], 0)
+    return SearchResult(ids=jnp.where(jnp.isinf(out_d), INVALID, out_ids),
+                        dists=out_d, hops=jnp.zeros((B,), jnp.int32),
+                        evals=evals.sum(axis=1))
 
 
 def medoid_seed(vectors: Array, n: int) -> int:

@@ -51,6 +51,14 @@ carried on the epoch) are appended to each lane's exclude list and
 dropped as session seeds.  Without publishing the engine behaves as
 before: it serves the index's own device cache and the index must stay
 read-only while the engine is live.
+
+Filtered search: ``submit(query, filter=(t1[, t2]))`` restricts a request
+to the rows carrying every tag of its filter (``core/tags.py``; a filter
+admitting fewer than k rows is refused at submit).  At dispatch each
+filtered request takes the beam with a filter operand, or, when its
+filter admits no more rows than the beam's mean distance evaluations per
+filtered request (:meth:`AsyncQueryEngine.scan_limit`), an exact scan of
+those rows; one flush may hold both, each its own bucketed program.
 """
 from __future__ import annotations
 
@@ -60,6 +68,10 @@ import threading
 from typing import Optional, Sequence
 
 import numpy as np
+
+from repro.core.beam import default_beam_width
+from repro.core.graph import INVALID, pow2_bucket
+from repro.core.tags import normalize_filter
 
 from repro.obs import clock
 from repro.obs.metrics import MetricsRegistry
@@ -88,6 +100,20 @@ class AsyncEngineStats:
     degraded: int = 0           # requests served below the base rung
     crashes: int = 0            # loop-thread deaths observed
     restarts: int = 0           # successful supervisor restarts
+
+
+@dataclasses.dataclass
+class _Routed:
+    """How a flush holding filtered requests was split (``buckets.route``):
+    the request positions the beam searched, in lane order, with its
+    bucket and which of them carry a filter, and those the scan read, with
+    its result."""
+
+    beam: np.ndarray
+    beam_bucket: int
+    beam_filtered: np.ndarray
+    scan: np.ndarray
+    scan_res: object = None
 
 
 class AsyncQueryEngine:
@@ -210,6 +236,36 @@ class AsyncQueryEngine:
         self._m_crashes = self.metrics.counter("serving_engine_crashes_total")
         self._m_restarts = self.metrics.counter(
             "serving_thread_restarts_total")
+        # filtered search: requests, lanes sent to the scan route, rows the
+        # scan read, distance evaluations of vertices a filter did not
+        # admit, and the two routes' program calls
+        self._m_filtered = self.metrics.counter(
+            "serving_filtered_requests_total")
+        self._m_scan_lanes = self.metrics.counter(
+            "serving_filter_scan_requests_total")
+        self._m_scan_rows = self.metrics.counter(
+            "serving_filter_scan_rows_total")
+        self._m_rejected = self.metrics.counter(
+            "serving_filter_rejected_evals_total")
+        self._m_scan_calls = self.metrics.counter(
+            "serving_filter_scan_calls_total")
+        self._m_beam_calls = self.metrics.counter(
+            "serving_filter_beam_calls_total")
+        # the scan route's shapes: a query lane for each request a flush
+        # may hold, each with at most what a beam of L entries scores when
+        # it expands them all, L x degree (the expected evaluations of a
+        # filtered beam before any has run, scan_limit), in chunks; the
+        # chunks of a flush in powers of two
+        degree = getattr(getattr(index, "params", None), "degree", 1)
+        self._beam_evals_prior = degree * (
+            beam_width or default_beam_width(k, degree, 1))
+        self.scan_lanes = pow2_bucket(self.max_batch)
+        self.scan_lane_chunks = -(-max(self._beam_evals_prior, 1)
+                                  // _buckets.SCAN_CHUNK)
+        self.scan_chunks = _buckets.bucket_sizes(
+            self.scan_lanes * self.scan_lane_chunks, 8)
+        self._filtered_beam_lanes = 0
+        self._filtered_beam_evals = 0
         # -- resilience: bounded admission + degradation ladder ------------
         self._validate = validate
         self.max_restarts = max_restarts
@@ -423,6 +479,11 @@ class AsyncQueryEngine:
                 seen.add(rung.cfg)
                 t = _buckets.precompile(view, rung.cfg, self.buckets,
                                         with_budget=True)
+                if getattr(view, "tagged", False):
+                    t.update(_buckets.precompile_filtered(
+                        view, rung.cfg, self.buckets, self.scan_lanes,
+                        self.scan_chunks, self.scan_lane_chunks,
+                        with_budget=True))
                 for (b, variant), secs in t.items():
                     times[(b, variant if i == 0 else f"r{i}-{variant}")] \
                         = secs
@@ -430,16 +491,41 @@ class AsyncQueryEngine:
             self.index.release_view(view)
         return times
 
+    def warm_filtered(self) -> dict:
+        """Compile the programs a filtered flush at the base rung
+        dispatches, without deadlines: the beam with a filter operand in
+        every bucket and the scan in every chunk bucket."""
+        view = self.index.acquire_view()
+        try:
+            return _buckets.precompile_filtered(
+                view, self.cfg, self.buckets, self.scan_lanes,
+                self.scan_chunks, self.scan_lane_chunks)
+        finally:
+            self.index.release_view(view)
+
+    def scan_limit(self) -> int:
+        """The most rows a filter may admit for its request to be scanned
+        rather than searched by the beam: the beam's mean distance
+        evaluations per filtered request so far (before any,
+        ``L x degree``), at most the rows of a lane's chunks."""
+        lanes = self._filtered_beam_lanes
+        expect = (self._filtered_beam_evals / lanes if lanes
+                  else self._beam_evals_prior)
+        return int(min(expect, self.scan_lane_chunks * _buckets.SCAN_CHUNK))
+
     # -- request path ------------------------------------------------------
     def submit(self, query: np.ndarray, *,
                deadline_ms: "float | None" = "unset",
                exclude: Sequence[int] = (),
-               seed_vertex: Optional[int] = None) -> AsyncResult:
+               seed_vertex: Optional[int] = None,
+               filter=None) -> AsyncResult:
         """Queue one query; returns immediately.  ``deadline_ms`` is
         relative to now ("unset" = the engine default; None = no SLO).
         ``seed_vertex`` replaces the medoid seed (exploration-style
         callers add it to ``exclude`` themselves when the protocol hides
-        it).
+        it).  ``filter``, one tag id or a tuple of two, restricts the
+        answer to the rows carrying every one of them (the index must
+        carry tags, and the filter admit at least k rows).
 
         Typed failure surface: raises
         :class:`~repro.resilience.RequestValidationError` for a malformed
@@ -461,13 +547,21 @@ class AsyncQueryEngine:
                 raise
         else:
             q = np.asarray(query, np.float32)
+        filt = ()
+        if filter is not None:
+            try:
+                filt = self._check_filter(filter)
+            except RequestValidationError:
+                self.stats.invalid += 1
+                self._m_invalid.inc()
+                raise
         dl_ms = self.default_deadline_ms if deadline_ms == "unset" \
             else deadline_ms
         deadline = None if dl_ms is None else clock.now() + dl_ms / 1e3
         try:
             res = self._queue.push(q, exclude=list(exclude),
                                    seed_vertex=seed_vertex,
-                                   deadline=deadline)
+                                   deadline=deadline, filter=filt)
         except OverloadError:
             self.stats.shed += 1
             self._m_shed.inc()
@@ -479,6 +573,28 @@ class AsyncQueryEngine:
             raise self._crashed
         self._m_queue_depth.set(len(self._queue))
         return res
+
+    def _check_filter(self, filter) -> tuple:
+        """The filter, normalized; a filter that is malformed, asks an
+        index without tags, or admits fewer than k rows is the caller's
+        error."""
+        try:
+            filt = normalize_filter(filter)
+        except ValueError as e:
+            raise RequestValidationError(str(e)) from None
+        view = self.index.acquire_view()
+        try:
+            if not getattr(view, "tagged", False):
+                raise RequestValidationError(
+                    "a filter needs an index whose rows carry tags")
+            admitted = view.filter_count(filt)
+        finally:
+            self.index.release_view(view)
+        if admitted < self.cfg.k:
+            raise RequestValidationError(
+                f"filter {filt} admits {admitted} rows, fewer than "
+                f"k={self.cfg.k}")
+        return filt
 
     def search(self, queries: np.ndarray, timeout: Optional[float] = 60.0
                ) -> tuple[np.ndarray, np.ndarray]:
@@ -551,7 +667,8 @@ class AsyncQueryEngine:
         B = len(reqs)
         bucket = next(b for b in self.buckets if b >= B)
         with span("deg.serve.dispatch", flush=self.stats.flushes,
-                  bucket=bucket, lanes=B):
+                  bucket=bucket, lanes=B,
+                  filtered=sum(1 for r in reqs if r.filter)):
             self._dispatch_flush(reqs, B, bucket)
 
     def _dispatch_flush(self, reqs: list[Request], B: int,
@@ -569,13 +686,13 @@ class AsyncQueryEngine:
         now = clock.now()
         expired = [r.deadline is not None and now > r.deadline for r in reqs]
         budget = None
+        base = _buckets.NO_BUDGET if rung.hop_budget is None \
+            else rung.hop_budget
         if any(expired) or rung.hop_budget is not None:
             # expired lanes run the partial-hop early extract; the rest
             # (and the padding) run the rung's cap, or uncapped at the
             # base rung.  One budgeted program per bucket regardless of
             # which lanes expired (traced operand).
-            base = _buckets.NO_BUDGET if rung.hop_budget is None \
-                else rung.hop_budget
             budget = np.full(bucket, base, np.int32)
             for i, ex in enumerate(expired):
                 if ex:
@@ -601,12 +718,20 @@ class AsyncQueryEngine:
                 if sv is not None and sv in qset:
                     sv = None
                 items.append(_buckets.BatchItem(
-                    query=r.query, exclude=excl_ids, seed_vertex=sv))
-            qs, seeds, excl = _buckets.pad_batch(items, bucket,
-                                                 view.medoid(),
-                                                 self._exclude_width)
-            res = _buckets.dispatch(view, rung.cfg, qs, seeds, excl,
-                                    hop_budget=budget)
+                    query=r.query, exclude=excl_ids, seed_vertex=sv,
+                    filter=r.filter))
+            routed = None
+            if any(r.filter for r in reqs):
+                res, routed = self._dispatch_routed(view, rung.cfg, items,
+                                                    budget, base)
+                for i in routed.scan:
+                    expired[i] = False      # a scan is exact, never partial
+            else:
+                qs, seeds, excl = _buckets.pad_batch(items, bucket,
+                                                     view.medoid(),
+                                                     self._exclude_width)
+                res = _buckets.dispatch(view, rung.cfg, qs, seeds, excl,
+                                        hop_budget=budget)
         except BaseException:
             self.index.release_view(view)
             raise
@@ -634,8 +759,39 @@ class AsyncQueryEngine:
         # extract releases the slot once the flush is drained.  A list,
         # not a tuple: slot 5 (the epoch view) is cleared in place on
         # release so the crash handler can't double-release it.
-        self._inflight.put([reqs, res, expired, bucket, clock.now(), view])
+        self._inflight.put([reqs, res, expired, bucket, clock.now(), view,
+                            routed])
         self._staging = None
+
+    def _dispatch_routed(self, view, cfg, items: list, budget, base):
+        """Dispatch a flush holding filtered requests: the beam lanes in
+        their bucket (with the filter operand where a lane has a filter),
+        the scanned lanes in theirs.  Returns (the beam's result or None,
+        the split)."""
+        beam, scan, rows = _buckets.route(items, view, self.scan_limit())
+        res, b = None, 0
+        if beam:
+            sub = [items[i] for i in beam]
+            b = next(x for x in self.buckets if x >= len(sub))
+            qs, seeds, excl = _buckets.pad_batch(sub, b, view.medoid(),
+                                                 self._exclude_width)
+            sub_budget = None
+            if budget is not None:
+                sub_budget = np.full(b, base, np.int32)
+                sub_budget[: len(beam)] = budget[beam]
+            res = _buckets.dispatch(view, cfg, qs, seeds, excl,
+                                    hop_budget=sub_budget,
+                                    filter_tags=_buckets.pad_filter(sub, b))
+        scan_res = None
+        if scan:
+            scan_res = _buckets.dispatch_scan(view, cfg, *_buckets.pad_scan(
+                [items[i].query for i in scan], rows, self.scan_lanes,
+                self.scan_chunks, self.scan_lane_chunks))
+        return res, _Routed(
+            beam=np.asarray(beam, np.int64), beam_bucket=b,
+            beam_filtered=np.asarray([bool(items[i].filter) for i in beam],
+                                     bool),
+            scan=np.asarray(scan, np.int64), scan_res=scan_res)
 
     # -- extract thread ----------------------------------------------------
     def _extract_loop(self) -> None:
@@ -647,11 +803,14 @@ class AsyncQueryEngine:
             # the crash handler fails the futures it had already dequeued
             self._extracting = item
             _faults.fire("extract.loop")
-            reqs, res, expired, bucket, t0, view = item
+            reqs, res, expired, bucket, t0, view, routed = item
             flush = reqs[0].result.flush_index
-            with span("deg.serve.readback", device=True, flush=flush):
-                ids = np.asarray(res.ids)      # device->host: blocks until
-                dists = np.asarray(res.dists)  # the async dispatch finished
+            if routed is None:
+                with span("deg.serve.readback", device=True, flush=flush):
+                    ids = np.asarray(res.ids)      # device->host: blocks
+                    dists = np.asarray(res.dists)  # until the dispatch ran
+            else:
+                ids, dists = self._read_routed(res, routed, len(reqs), flush)
             t_dev = clock.now()
             with span("deg.serve.complete", flush=flush):
                 self._complete_flush(item, ids, dists, t_dev)
@@ -659,11 +818,63 @@ class AsyncQueryEngine:
             self._slots.release()     # free the dispatch slot last, so a
             # newly formed batch sees this flush's arrivals in the queue
 
+    def _read_routed(self, res, routed: _Routed, B: int, flush: int
+                     ) -> tuple[np.ndarray, np.ndarray]:
+        """The answers of a routed flush, in request order: the beam's
+        read back, then the scan's."""
+        k = self.cfg.k
+        ids = np.full((B, k), INVALID, np.int32)
+        dists = np.full((B, k), np.inf, np.float32)
+        if res is not None:
+            with span("deg.serve.readback", device=True, flush=flush):
+                nb = len(routed.beam)
+                ids[routed.beam] = np.asarray(res.ids)[:nb]
+                dists[routed.beam] = np.asarray(res.dists)[:nb]
+        if routed.scan_res is not None:
+            with span("deg.serve.scan", device=True, flush=flush):
+                ns = len(routed.scan)
+                ids[routed.scan] = np.asarray(routed.scan_res.ids)[:ns]
+                dists[routed.scan] = np.asarray(routed.scan_res.dists)[:ns]
+        return ids, dists
+
+    def _routed_counters(self, res, routed: _Routed, B: int):
+        """(hops, evals, visited share) per request of a routed flush, and
+        its filter counters."""
+        hops = np.zeros(B, np.int64)
+        evals = np.zeros(B, np.int64)
+        vfrac = None
+        filt = routed.beam_filtered
+        if res is not None:
+            nb = len(routed.beam)
+            hops[routed.beam] = np.asarray(res.hops)[:nb]
+            evals[routed.beam] = np.asarray(res.evals)[:nb]
+            if res.trips is not None:
+                self._m_lane_trips.inc(int(res.trips) * routed.beam_bucket)
+            if res.rejected is not None:
+                self._m_rejected.inc(int(np.asarray(res.rejected)[:nb]
+                                         [filt].sum()))
+            if res.visited_frac is not None:
+                vfrac = np.full(B, np.nan)
+                vfrac[routed.beam] = np.asarray(res.visited_frac)[:nb]
+            if filt.any():
+                self._m_beam_calls.inc()
+                self._filtered_beam_lanes += int(filt.sum())
+                self._filtered_beam_evals += int(
+                    evals[routed.beam[filt]].sum())
+        ns = len(routed.scan)
+        if ns:
+            evals[routed.scan] = np.asarray(routed.scan_res.evals)[:ns]
+            self._m_scan_calls.inc()
+            self._m_scan_lanes.inc(ns)
+            self._m_scan_rows.inc(int(evals[routed.scan].sum()))
+        self._m_filtered.inc(int(filt.sum()) + ns)
+        return hops, evals, vfrac
+
     def _complete_flush(self, item: list, ids: np.ndarray, dists: np.ndarray,
                         t_dev: float) -> None:
         """Counters, futures and query log of one flush whose answers are
         on host; releases its epoch view."""
-        reqs, res, expired, bucket, t0, view = item
+        reqs, res, expired, bucket, t0, view, routed = item
         B = len(reqs)
         dt = t_dev - t0
         self.stats.ema_flush_s = dt if not self.stats.ema_flush_s \
@@ -672,14 +883,17 @@ class AsyncQueryEngine:
         # traversal counters ride the same result the flush computed
         # anyway — surfacing them costs three tiny transfers, zero
         # extra device work
-        hops = np.asarray(res.hops)
-        evals = np.asarray(res.evals)
+        if routed is None:
+            hops = np.asarray(res.hops)
+            evals = np.asarray(res.evals)
+            if res.trips is not None:
+                self._m_lane_trips.inc(int(res.trips) * bucket)
+            vfrac = None if res.visited_frac is None \
+                else np.asarray(res.visited_frac)
+        else:
+            hops, evals, vfrac = self._routed_counters(res, routed, B)
         self._m_hops.inc(int(hops[:B].sum()))
         self._m_evals.inc(int(evals[:B].sum()))
-        if res.trips is not None:
-            self._m_lane_trips.inc(int(res.trips) * bucket)
-        vfrac = None if res.visited_frac is None \
-            else np.asarray(res.visited_frac)
         # every device read of this flush is on host: drop the epoch
         # reference (clearing the slot keeps a crash-drain from
         # double-releasing this item)

@@ -26,6 +26,15 @@ is the middle ground both engines share:
 * :func:`precompile` — boot-time warmup: traces and compiles every
   (bucket, variant) program so no live request ever pays a trace
   (``launch/serve.py --warmup``, ``AsyncQueryEngine.warmup``).
+
+Filtered requests (``core/tags.py``) take one of two routes, chosen per
+request by :func:`route` from the rows its filter admits: the beam with a
+fixed-width filter operand (:func:`pad_filter`), or, where the filter
+admits no more rows than the beam is expected to score, an exact scan of
+those rows (:func:`pad_scan`, :func:`dispatch_scan`): the scanned
+requests' rows in chunks of :data:`SCAN_CHUNK`, bucketed by the chunks'
+count.  A flush with no filter dispatches the filter-free program,
+as one with no exclusions dispatches the exclusion-free one.
 """
 from __future__ import annotations
 
@@ -35,11 +44,15 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.core.graph import INVALID, pow2_bucket
+from repro.core.tags import MAX_FILTER_TAGS
 from repro.obs import clock
 
 #: hop budget meaning "unlimited" for non-expired lanes in a budgeted
 #: batch (any value above the engine's max_hops bound behaves as no cap)
 NO_BUDGET = np.int32(2**31 - 1)
+
+#: rows a chunk of the scan route holds (each request's own chunks)
+SCAN_CHUNK = 256
 
 
 def bucket_sizes(max_batch: int, floor: int = 8) -> tuple[int, ...]:
@@ -93,6 +106,7 @@ class BatchItem:
     query: np.ndarray
     exclude: Sequence[int] = ()
     seed_vertex: Optional[int] = None
+    filter: tuple = ()          # tag ids ANDed, () = every row admitted
 
 
 def pad_batch(items: Sequence[BatchItem], bucket: int, medoid: int,
@@ -125,22 +139,90 @@ def pad_batch(items: Sequence[BatchItem], bucket: int, medoid: int,
     return qs, seeds, excl
 
 
+def pad_filter(items: Sequence[BatchItem], bucket: int
+               ) -> Optional[np.ndarray]:
+    """The (bucket, MAX_FILTER_TAGS) int32 filter operand, INVALID-padded
+    (a lane without a filter admits every row), or None when no request
+    carries a filter: the filter-free program."""
+    if not any(it.filter for it in items):
+        return None
+    out = np.full((bucket, MAX_FILTER_TAGS), INVALID, np.int32)
+    for i, it in enumerate(items):
+        out[i, : len(it.filter)] = it.filter
+    return out
+
+
+def route(items: Sequence[BatchItem], view, limit: int
+          ) -> tuple[list[int], list[int], list[np.ndarray]]:
+    """Split a flush by route: a filtered request whose filter admits at
+    most ``limit`` rows of ``view`` is scanned, every other request goes
+    to the beam.  Returns (beam positions, scan positions, each scanned
+    request's admitted rows, its exclude ids left out)."""
+    beam, scan, rows = [], [], []
+    for i, it in enumerate(items):
+        if it.filter and view.filter_count(it.filter) <= limit:
+            r = view.filter_rows(it.filter)
+            if len(it.exclude):
+                r = r[~np.isin(r, it.exclude)]
+            scan.append(i)
+            rows.append(r)
+        else:
+            beam.append(i)
+    return beam, scan, rows
+
+
+def pad_scan(queries: Sequence[np.ndarray], rows: Sequence[np.ndarray],
+             lanes: int, chunk_buckets: Sequence[int], lane_chunks: int
+             ) -> tuple[np.ndarray, ...]:
+    """Scanned requests -> fixed-shape operands of ``filtered_scan``:
+    ``lanes`` query lanes; each request's rows in chunks of
+    :data:`SCAN_CHUNK`, INVALID-padded, the chunks padded to the first
+    chunk bucket that holds them; the lane of each chunk; and each lane's
+    chunks, ``lane_chunks`` of them at most (the padding points past the
+    chunks)."""
+    B = len(queries)
+    qs = np.zeros((lanes, len(queries[0])), np.float32)
+    qs[:B] = queries
+    per = [-(-len(r) // SCAN_CHUNK) for r in rows]
+    n = next(b for b in chunk_buckets if b >= sum(per))
+    chunks = np.full((n, SCAN_CHUNK), INVALID, np.int32)
+    chunk_lane = np.full(n, lanes, np.int32)
+    lane_chunk = np.full((lanes, lane_chunks), n, np.int32)
+    at = 0
+    for i, r in enumerate(rows):
+        chunks.reshape(-1)[at * SCAN_CHUNK: at * SCAN_CHUNK + len(r)] = r
+        chunk_lane[at: at + per[i]] = i
+        lane_chunk[i, : per[i]] = np.arange(at, at + per[i])
+        at += per[i]
+    return qs, chunks, chunk_lane, lane_chunk
+
+
 def dispatch(index, cfg: ProgramConfig, qs: np.ndarray, seeds: np.ndarray,
              excl: Optional[np.ndarray],
-             hop_budget: Optional[np.ndarray] = None):
+             hop_budget: Optional[np.ndarray] = None,
+             filter_tags: Optional[np.ndarray] = None):
     """The one ``search_batch`` call site both engines flush through.
 
     ``index`` is whatever ``DEGIndex.acquire_view()`` returned: the index
     itself (single-writer mode) or an immutable
     :class:`repro.core.epoch.PublishedEpoch` (live mutation under
     serving) — both expose the same ``search_batch`` surface, and their
-    operand shapes match, so they share the compiled beam programs."""
+    operand shapes match, so they share the compiled beam programs.
+    ``filter_tags`` (:func:`pad_filter`) restricts each lane's results
+    to the rows its filter admits."""
+    filt = {} if filter_tags is None else {"filter_tags": filter_tags}
     return index.search_batch(
         qs, seeds, excl, k=cfg.k, eps=cfg.eps, beam_width=cfg.beam_width,
         quantized=None if cfg.codec == "float32" else cfg.codec,
         rerank_k=cfg.rerank_k, expand_width=cfg.expand_width,
         visited_size=cfg.visited_size, hop_backend=cfg.hop_backend,
-        hop_budget=hop_budget)
+        hop_budget=hop_budget, **filt)
+
+
+def dispatch_scan(index, cfg: ProgramConfig, *operands):
+    """The scan route's call (:func:`pad_scan`'s operands): the exact k
+    nearest of each lane's rows."""
+    return index.scan_batch(*operands, k=cfg.k)
 
 
 def precompile(index, cfg: ProgramConfig, buckets: Sequence[int], *,
@@ -171,4 +253,38 @@ def precompile(index, cfg: ProgramConfig, buckets: Sequence[int], *,
             res = dispatch(index, cfg, qs, seeds, excl, hop_budget=budget)
             jax.block_until_ready(res.ids)
             times[(b, name)] = clock.now() - t0
+    return times
+
+
+def precompile_filtered(index, cfg: ProgramConfig, buckets: Sequence[int],
+                        lanes: int, chunk_buckets: Sequence[int],
+                        lane_chunks: int, *,
+                        with_budget: bool = False) -> dict[tuple, float]:
+    """:func:`precompile` for filtered flushes: the beam with a filter
+    operand in every bucket (and budgeted, with ``with_budget``), and the
+    scan of ``lanes`` query lanes in every chunk bucket."""
+    import jax
+
+    dim = index.dim
+    medoid = index.medoid()
+    times: dict[tuple, float] = {}
+    for b in buckets:
+        items = [BatchItem(query=np.zeros(dim, np.float32), filter=(0,))] * b
+        qs, seeds, excl = pad_batch(items, b, medoid)
+        for budget in [None] + ([np.full(b, NO_BUDGET, np.int32)]
+                                if with_budget else []):
+            t0 = clock.now()
+            res = dispatch(index, cfg, qs, seeds, excl, hop_budget=budget,
+                           filter_tags=pad_filter(items, b))
+            jax.block_until_ready(res.ids)
+            times[(b, "filter" if budget is None else "filter-budget")] = \
+                clock.now() - t0
+    for n in chunk_buckets:
+        t0 = clock.now()
+        res = dispatch_scan(index, cfg, np.zeros((lanes, dim), np.float32),
+                            np.zeros((n, SCAN_CHUNK), np.int32),
+                            np.zeros(n, np.int32),
+                            np.zeros((lanes, lane_chunks), np.int32))
+        jax.block_until_ready(res.ids)
+        times[(lanes, f"scan-{n}")] = clock.now() - t0
     return times

@@ -168,6 +168,7 @@ class Request:
     seq: int                             # admission order (FIFO key)
     exclude: Sequence[int] = ()
     seed_vertex: Optional[int] = None
+    filter: tuple = ()                   # tag ids ANDed (core/tags.py)
 
     @property
     def deadline(self) -> Optional[float]:
@@ -231,7 +232,8 @@ class AdmissionQueue:
 
     def push(self, query, *, exclude: Sequence[int] = (),
              seed_vertex: Optional[int] = None,
-             deadline: Optional[float] = None) -> AsyncResult:
+             deadline: Optional[float] = None,
+             filter: tuple = ()) -> AsyncResult:
         res = AsyncResult(deadline=deadline)
         victim: Optional[Request] = None
         with self._cv:
@@ -247,7 +249,8 @@ class AdmissionQueue:
                         "your deadline", depth=self.capacity,
                         capacity=self.capacity, shed_at="queue"))
             req = Request(query=query, result=res, seq=self._seq,
-                          exclude=exclude, seed_vertex=seed_vertex)
+                          exclude=exclude, seed_vertex=seed_vertex,
+                          filter=filter)
             res.seq = req.seq
             self._seq += 1
             self._dq.append(req)

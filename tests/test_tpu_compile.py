@@ -129,6 +129,40 @@ def test_default_range_search_fits_v5e(one_chip):
     assert "tpu_custom_call" not in compiled.as_text()
 
 
+#: the filtered deployment (yfcc-filter-192-l2): rows, dim, degree, tag
+#: slots, and the scan's chunks a lane (L x degree rows of 256)
+FILTERED = (100000, 192, 20, 16, 10)
+
+
+@pytest.mark.parametrize("program", ["beam", "scan"])
+def test_filtered_programs_fit_v5e(one_chip, program):
+    """A filtered flush's two programs at the filtered deployment's size:
+    the beam with the tag table and a (64, 2) filter operand, and the
+    scan of its widest chunk bucket."""
+    from repro.core.graph import DEGraph
+    from repro.core.search import filtered_scan, range_search
+
+    N, m, d, T, J = FILTERED
+    S = lambda shape, dt=jnp.float32: _spec(one_chip, shape, dt)  # noqa: E731
+    if program == "beam":
+        graph = DEGraph(adjacency=S((N, d), jnp.int32), weights=S((N, d)),
+                        n=S((), jnp.int32))
+        lowered = range_search.lower(
+            graph, S((N, m)), S((BATCH, m)), S((BATCH, 1), jnp.int32),
+            k=10, eps=0.2, beam_width=128, tags=S((N, T), jnp.int32),
+            filter_tags=S((BATCH, 2), jnp.int32))
+    else:
+        C = BATCH * J
+        lowered = filtered_scan.lower(S((N, m)), S((BATCH, m)),
+                                      S((C, 256), jnp.int32),
+                                      S((C,), jnp.int32),
+                                      S((BATCH, J), jnp.int32), k=10)
+    mem = lowered.compile().memory_analysis()
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, mem
+
+
 @pytest.mark.parametrize("table", ["search_format", "default_format"])
 def test_refine_search_reads_table_in_place(one_chip, table):
     """Alg. 4's single-lane search (1 lane, 2 seeds, k 30, eps 0.001) over
